@@ -272,6 +272,26 @@ class VolunteerTrainer:
         return (after.get(group, {}).get(key, 0)
                 - before.get(group, {}).get(key, 0))
 
+    def _fold_round(self, step: int):
+        """-> (losses, mean gradient) of this round's validated units.
+
+        Every cached unit gradient is dropped before returning, so the
+        optimizer step that follows holds one gradient image on the
+        device, not one per unit plus their sum."""
+        round_units = sorted(uid for uid in self._completed
+                             if uid // self.micro_batches == step)
+        losses, grads = [], None
+        for uid in round_units:
+            loss, g = self._grad_cache[self._completed.pop(uid)]
+            self.tmetrics.folds.inc()
+            if self.tel.tracing:
+                self.tel.event("fold", unit=uid, round=step)
+            losses.append(loss)
+            grads = g if grads is None else jax.tree.map(
+                lambda a, b: a + b, grads, g)
+        self._grad_cache.clear()
+        return losses, jax.tree.map(lambda g: g / self.micro_batches, grads)
+
     def round(self, step: int) -> RoundStats:
         base_index = self.cursor.next_index
         for k in range(self.micro_batches):
@@ -320,18 +340,7 @@ class VolunteerTrainer:
         drained = self.sched.drain_completed()
         self._settle_uplink_credit(drained)
         self._completed.update(drained)
-        round_units = sorted(uid for uid in self._completed
-                             if uid // self.micro_batches == step)
-        losses, grads = [], None
-        for uid in round_units:
-            loss, g = self._grad_cache[self._completed.pop(uid)]
-            self.tmetrics.folds.inc()
-            if self.tel.tracing:
-                self.tel.event("fold", unit=uid, round=step)
-            losses.append(loss)
-            grads = g if grads is None else jax.tree.map(
-                lambda a, b: a + b, grads, g)
-        grads = jax.tree.map(lambda g: g / self.micro_batches, grads)
+        losses, grads = self._fold_round(step)
         if self.compress_grads:
             from repro.optim import grad_compress
             if self._compress_err is None:
@@ -340,7 +349,7 @@ class VolunteerTrainer:
                 grads, self._compress_err)
             grads = grad_compress.decompress(comp, grads)
         self.state = self.apply_fn(self.state, grads)
-        self._grad_cache.clear()
+        del grads            # the snapshot probe below needs the room
 
         snapshot_stall_ms, snapshot_bytes = 0.0, 0
         if (self.snapshots is not None and self.snapshot_every
@@ -427,6 +436,9 @@ class VolunteerTrainer:
                                       "bytes_dedup": dedup,
                                       "route": route}
         state, aux = self.snapshots.restore(target_tree=abstract_state)
-        self.state = state
+        # back on the device, where training keeps it: host numpy leaves
+        # would run part of the eager optimizer in numpy, whose rounding
+        # (e.g. of the bias-correction power) differs from the device's
+        self.state = jax.device_put(state)
         self.cursor = Cursor.from_state(aux["cursor"])
         return int(aux["round"]) + 1

@@ -205,6 +205,11 @@ class SnapshotManager:
         return self._writer is not None
 
     @property
+    def mirror_bytes(self) -> int:
+        """Device bytes the probe-side mirror tiles hold."""
+        return self._device_mirror.nbytes()
+
+    @property
     def writer_stats(self) -> dict:
         return dict(self._writer.stats) if self._writer is not None else {}
 

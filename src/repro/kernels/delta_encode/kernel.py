@@ -10,6 +10,13 @@ FLOPs — ideal Pallas shape: 1-D grid over (8, 1024)-element VMEM tiles
 Deltas are XOR on the int32 bit pattern: exact for any float (including
 NaN/Inf payloads), and unchanged blocks are all-zero → maximally
 compressible downstream.  decode(old, delta) == new bit-for-bit.
+
+The per-tile changed flags are written lane-dense: one (8, 128) int32
+output block holds the flags of ``FLAGS_PER_BLOCK`` consecutive tiles and
+stays resident in VMEM while the grid walks them (a revisited output
+block, so every grid axis here is sequential — ``"arbitrary"``).  The
+wrapper flattens the blocks and slices the first ``nblk`` flags, so any
+tile count works and only one vector register is touched per tile.
 """
 from __future__ import annotations
 
@@ -23,23 +30,56 @@ from jax.experimental.pallas import tpu as pltpu
 LANE = 1024
 SUB = 8
 TILE = SUB * LANE   # 8192 elements per grid step
+FLAG_LANES = 128
+FLAGS_PER_BLOCK = SUB * FLAG_LANES   # tile flags per (8, 128) bitmap block
+
+_TILE_SPEC = pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0))
+_FLAG_SPEC = pl.BlockSpec((SUB, FLAG_LANES),
+                          lambda i: (i // FLAGS_PER_BLOCK, 0))
+_SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
-def _delta_kernel(old_ref, new_ref, delta_ref, changed_ref):
-    o = old_ref[...]
-    n = new_ref[...]
-    d = jax.lax.bitwise_xor(o, n)
+def _flag_shape(nblk: int) -> jax.ShapeDtypeStruct:
+    nb = -(-nblk // FLAGS_PER_BLOCK)
+    return jax.ShapeDtypeStruct((nb * SUB, FLAG_LANES), jnp.int32)
+
+
+def _tile_changed(d) -> jax.Array:
+    """0-d int32: 1 when any element of the XOR tile is nonzero."""
+    return jnp.max(jnp.where(d != 0, 1, 0).astype(jnp.int32))
+
+
+def _set_flag(flags_ref, changed) -> None:
+    """Write this grid step's flag into its slot of the resident block."""
+    j = pl.program_id(0) % FLAGS_PER_BLOCK
+
+    @pl.when(j == 0)
+    def _zero():
+        flags_ref[...] = jnp.zeros(flags_ref.shape, jnp.int32)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, flags_ref.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, flags_ref.shape, 1)
+    flags_ref[...] = jnp.where(row * FLAG_LANES + col == j, changed,
+                               flags_ref[...])
+
+
+def _flags_out(flags: jax.Array, nblk: int) -> jax.Array:
+    return flags.reshape(-1)[:nblk]
+
+
+def _delta_kernel(old_ref, new_ref, delta_ref, flags_ref):
+    d = jax.lax.bitwise_xor(old_ref[...], new_ref[...])
     delta_ref[...] = d
-    changed_ref[0] = jnp.any(d != 0).astype(jnp.int32)
+    _set_flag(flags_ref, _tile_changed(d))
 
 
 def _apply_kernel(old_ref, delta_ref, new_ref):
     new_ref[...] = jax.lax.bitwise_xor(old_ref[...], delta_ref[...])
 
 
-def _bitmap_kernel(old_ref, new_ref, changed_ref):
+def _bitmap_kernel(old_ref, new_ref, flags_ref):
     d = jax.lax.bitwise_xor(old_ref[...], new_ref[...])
-    changed_ref[0] = jnp.any(d != 0).astype(jnp.int32)
+    _set_flag(flags_ref, _tile_changed(d))
 
 
 def _as_tiles(flat_i32: jax.Array):
@@ -60,18 +100,17 @@ def delta_encode(old: jax.Array, new: jax.Array, *,
     o32, _ = _as_tiles(_bitcast_i32(old))
     n32, n = _as_tiles(_bitcast_i32(new))
     nblk = o32.shape[0]
-    delta, changed = pl.pallas_call(
+    delta, flags = pl.pallas_call(
         _delta_kernel,
         grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=[_TILE_SPEC, _FLAG_SPEC],
         out_shape=[jax.ShapeDtypeStruct((nblk, SUB, LANE), jnp.int32),
-                   jax.ShapeDtypeStruct((nblk,), jnp.int32)],
+                   _flag_shape(nblk)],
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
     )(o32, n32)
-    return delta, changed, n
+    return delta, _flags_out(flags, nblk), n
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -88,26 +127,26 @@ def changed_bitmap(old: jax.Array, new: jax.Array, *,
     o32, _ = _as_tiles(_bitcast_i32(old))
     n32, n = _as_tiles(_bitcast_i32(new))
     nblk = o32.shape[0]
-    changed = pl.pallas_call(
+    flags = pl.pallas_call(
         _bitmap_kernel,
         grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nblk,), jnp.int32),
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=_FLAG_SPEC,
+        out_shape=_flag_shape(nblk),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
     )(o32, n32)
-    return changed, n
+    return _flags_out(flags, nblk), n
 
 
-def _fused_kernel(old_ref, new_ref, bitmap_ref, tiles_ref,
+def _fused_kernel(old_ref, new_ref, flags_ref, tiles_ref,
                   cnt_ref, stage_ref, sem):
     """Probe + gather in one pass: XOR the tile, flag it, and — only when it
     changed — DMA the compacted tile into the next free output slot.
 
-    The SMEM counter persists across grid steps (TPU grids run sequentially
-    per core), so compacted tiles land in ascending tile order and the host
-    can recover tile indices from the bitmap alone."""
+    The SMEM counter persists across grid steps (the grid axis is declared
+    sequential), so compacted tiles land in ascending tile order and the
+    host can recover tile indices from the bitmap alone."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -115,10 +154,10 @@ def _fused_kernel(old_ref, new_ref, bitmap_ref, tiles_ref,
         cnt_ref[0] = 0
 
     d = jax.lax.bitwise_xor(old_ref[...], new_ref[...])
-    changed = jnp.any(d != 0)
-    bitmap_ref[0] = changed.astype(jnp.int32)
+    changed = _tile_changed(d)
+    _set_flag(flags_ref, changed)
 
-    @pl.when(changed)
+    @pl.when(changed != 0)
     def _emit():
         c = cnt_ref[0]
         stage_ref[...] = d
@@ -154,21 +193,20 @@ def fused_delta_tiles(o32: jax.Array, n32: jax.Array, *,
     inputs — the launch the bucketed tree diff issues once per size bucket
     (inputs are per-leaf ``as_i32_tiles`` views concatenated on device)."""
     nblk = o32.shape[0]
-    bitmap, tiles = pl.pallas_call(
+    flags, tiles = pl.pallas_call(
         _fused_kernel,
         grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((1,), lambda i: (i,)),
-                   pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_shape=[jax.ShapeDtypeStruct((nblk,), jnp.int32),
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=[_FLAG_SPEC, pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=[_flag_shape(nblk),
                    jax.ShapeDtypeStruct((nblk, SUB, LANE), jnp.int32)],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
                         pltpu.VMEM((1, SUB, LANE), jnp.int32),
                         pltpu.SemaphoreType.DMA],
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
     )(o32, n32)
-    return bitmap, tiles
+    return _flags_out(flags, nblk), tiles
 
 
 def as_i32_tiles(x: jax.Array):
@@ -201,9 +239,8 @@ def delta_apply(old: jax.Array, delta: jax.Array, *,
     new32 = pl.pallas_call(
         _apply_kernel,
         grid=(o32.shape[0],),
-        in_specs=[pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, SUB, LANE), lambda i: (i, 0, 0)),
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=_TILE_SPEC,
         out_shape=jax.ShapeDtypeStruct(o32.shape, jnp.int32),
         interpret=interpret,
     )(o32, delta)

@@ -32,6 +32,9 @@ host mirror.  The numpy ``ref`` mode mirrors every kernel bit-for-bit
 ``KERNEL_STATS`` counts launches and streamed bytes (ref-mode passes count
 as one launch each) — ``benchmarks/roofline.py`` reads it to prove
 launches-per-snapshot is O(buckets) and the probe runs at memory bandwidth.
+``ref_passes`` separately counts every leaf set the numpy oracle handled
+(ref mode, or a kernel-mode leaf whose dtype the kernel cannot bitcast),
+so a run that was meant to use the compiled kernel can assert it did.
 """
 from __future__ import annotations
 
@@ -57,8 +60,10 @@ KERNEL_DTYPES = ("int32", "float32", "bfloat16", "float16", "int16")
 MAX_BUCKET_TILES = 256
 
 # launch/bandwidth accounting for benchmarks/roofline.py; a ref-mode pass
-# over a (concatenated) tile view counts as one launch
-KERNEL_STATS = {"launches": 0, "probe_bytes": 0, "d2h_bytes": 0}
+# over a (concatenated) tile view counts as one launch, and also as one of
+# ``ref_passes`` (numpy-oracle passes, seeding ones included)
+KERNEL_STATS = {"launches": 0, "probe_bytes": 0, "d2h_bytes": 0,
+                "ref_passes": 0}
 
 
 def reset_kernel_stats() -> dict:
@@ -215,6 +220,7 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
         else int(np.asarray(old).nbytes)
     if mode == "ref":
         bitmap, tiles = fused_records_ref(old, new)
+        KERNEL_STATS["ref_passes"] += 1
         _count_launch(bitmap.size * TILE_BYTES, 0)
     elif fused:
         interpret = (mode == "interpret")
@@ -375,6 +381,7 @@ def _probe_slot(key, leaf, meta: tuple, mode: str, mirror: DeviceMirror):
         # same immutable array as last round: unchanged by construction
         return _EMPTY_TILES, np.zeros(ntiles, np.int32), nbytes
     if mode == "ref":
+        KERNEL_STATS["ref_passes"] += 1
         n32 = _ref_tiles(leaf)
         o32 = mirror.get(key, layout)
         mirror.swap(key, layout, n32, (leaf,))
@@ -424,6 +431,7 @@ def _probe_bucket(bid: int, leaves: list, news: dict, mode: str,
         return {key: (_EMPTY_TILES, np.zeros(nt, np.int32), nb)
                 for key, nb, nt, _ in leaves}
     if mode == "ref":
+        KERNEL_STATS["ref_passes"] += 1
         parts = [_ref_tiles(x) for x in leaf_objs]
         n32 = parts[0] if len(parts) == 1 else np.concatenate(parts)
         o32 = mirror.get(skey, layout)
@@ -539,6 +547,7 @@ def _diff_bucket(bid: int, leaves: list, olds: dict, news: dict,
         o32 = np.concatenate([_ref_tiles(olds[k]) for k, _, _ in leaves])
         n32 = np.concatenate([_ref_tiles(news[k]) for k, _, _ in leaves])
         bitmap, tiles = fused_tiles_ref(o32, n32)
+        KERNEL_STATS["ref_passes"] += 1
         _count_launch(n32.nbytes, 0)
     else:
         import jax

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs.base import get_arch, reduced
 from repro.distributed.sharding import init_tree
+from repro.launch.jaxcache import use_compile_cache
 from repro.models import api
 from repro.models.lm import RunConfig
 
@@ -33,6 +34,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = reduced(get_arch(args.arch))
     run = RunConfig(remat="none", block_kv=128, ssm_chunk=32)

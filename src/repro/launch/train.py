@@ -10,9 +10,15 @@ snapshots, and survives worker failures / restarts.
     # crash it, then:
     ... --resume --steps 50       # continues bit-exactly from the snapshot
 
-``--preset full`` keeps the assigned architecture (TPU-scale; use the
-dry-run on CPU); ``--preset smoke``/``--preset 100m`` build reduced
-same-family configs sized for this container.
+``--preset full`` keeps the assigned architecture at every published
+width; ``--layers N`` cuts its depth only (the summary's ``reduced``
+records the cut), which is how one chip holds it:
+
+    python -m repro.launch.train --arch granite-3-2b --preset full \
+        --layers 1 --seq 1024 --batch 2 --snapshot-every 1 --async-writer
+
+``--preset smoke``/``--preset 100m`` build reduced same-family configs
+sized for a CPU.
 """
 from __future__ import annotations
 
@@ -33,22 +39,34 @@ from repro.core.scheduler import SimClock, VolunteerScheduler
 from repro.core.snapshots import SnapshotManager
 from repro.data.pipeline import DataConfig, TokenStream
 from repro.distributed.sharding import init_tree
+from repro.launch.jaxcache import use_compile_cache
 from repro.models import api
 from repro.models.lm import RunConfig
 from repro.optim import adamw
 
 
-def build_arch(name: str, preset: str):
+def build_arch(name: str, preset: str, layers: int = 0):
+    """-> (config, ``reduced`` record of the cuts made to the full preset).
+
+    ``layers`` cuts the depth of ``full`` only; every width stays as
+    published."""
     cfg = get_arch(name)
-    if preset == "full":
-        return cfg
-    if preset == "smoke":
-        return reduced(cfg)
-    if preset == "100m":
-        # ~100M-param same-family config (example application scale)
-        return reduced(cfg, n_layers=6, d_model=512, n_heads=8,
-                       n_kv_heads=4, d_ff=2048, vocab_size=32768)
-    raise ValueError(preset)
+    if preset != "full":
+        if layers:
+            raise ValueError("--layers cuts the depth of --preset full only")
+        if preset == "smoke":
+            return reduced(cfg), {}
+        if preset == "100m":
+            # ~100M-param same-family config (example application scale)
+            return reduced(cfg, n_layers=6, d_model=512, n_heads=8,
+                           n_kv_heads=4, d_ff=2048, vocab_size=32768), {}
+        raise ValueError(preset)
+    if not layers or layers == cfg.n_layers:
+        return cfg, {}
+    if not 0 < layers < cfg.n_layers:
+        raise ValueError(f"--layers must be in 1..{cfg.n_layers}")
+    return (dataclasses.replace(cfg, n_layers=layers),
+            {"n_layers": [layers, cfg.n_layers]})
 
 
 def main(argv=None) -> dict:
@@ -56,6 +74,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--arch", default="granite-3-2b")
     ap.add_argument("--preset", default="smoke",
                     choices=["smoke", "100m", "full"])
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut --preset full to N layers (depth only; every "
+                         "width stays as published)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--batch", type=int, default=8, help="per micro-batch")
@@ -123,12 +144,10 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args(argv)
+    use_compile_cache()
+    t_setup = time.perf_counter()
 
-    if args.preset == "full":
-        raise SystemExit("--preset full is TPU-scale; use "
-                         "repro.launch.dryrun on this container")
-
-    cfg = build_arch(args.arch, args.preset)
+    cfg, cuts = build_arch(args.arch, args.preset, args.layers)
     run = RunConfig(remat="none", block_kv=min(args.seq, 512), ssm_chunk=64)
     specs = api.state_specs(cfg)
     oc = adamw.AdamWConfig(lr=args.lr, warmup_steps=10,
@@ -185,8 +204,6 @@ def main(argv=None) -> dict:
                                    capacity_bytes=args.edge_capacity)
                          for i in range(args.edge_caches)],
                         scheduler=sched)
-    state = api.TrainState(init_tree(specs.params, jax.random.key(args.seed)),
-                           init_tree(specs.opt, jax.random.key(args.seed)))
 
     server = None
     if args.uplink:
@@ -199,8 +216,14 @@ def main(argv=None) -> dict:
         server.publish(Project("train", spec, scheduler=sched))
         server.register_user("launcher")
 
+    # the initial state is handed over, not kept: a local reference here
+    # would pin a whole extra state image on the device for the run
     trainer = VolunteerTrainer(
-        grad_fn=grad_fn, apply_fn=apply_fn, state=state, stream=stream,
+        grad_fn=grad_fn, apply_fn=apply_fn,
+        state=api.TrainState(
+            init_tree(specs.params, jax.random.key(args.seed)),
+            init_tree(specs.opt, jax.random.key(args.seed))),
+        stream=stream,
         micro_batches=args.micro, scheduler=sched, snapshots=snaps,
         snapshot_every=args.snapshot_every, seed=args.seed,
         compress_grads=args.compress_grads,
@@ -236,12 +259,16 @@ def main(argv=None) -> dict:
     trainer.respawn = lambda tr: spawn(1)
 
     t0 = time.time()
+    setup_s = time.perf_counter() - t_setup
     rebalance_splits = 0
+    step_s = []
     for s in range(start_step, start_step + args.steps):
         alive = sum(w.alive for w in trainer.workers.values())
         if alive < args.workers:
             spawn(args.workers - alive)
+        ts = time.perf_counter()
         st = trainer.round(s)
+        step_s.append(time.perf_counter() - ts)
         if args.rebalance and args.shards > 1:
             moved = sched.rebalance()
             if moved is not None:
@@ -262,9 +289,16 @@ def main(argv=None) -> dict:
     wall = time.time() - t0
     tokens = args.steps * args.micro * args.batch * args.seq
     summary = {
-        "arch": cfg.name, "steps": args.steps, "wall_s": round(wall, 2),
+        "arch": cfg.name, "reduced": cuts,
+        "steps": args.steps, "wall_s": round(wall, 2),
+        "setup_s": round(setup_s, 2),
+        "step_s": [round(x, 3) for x in step_s],
         "tokens_per_s": round(tokens / wall, 1),
+        "losses": [h.loss for h in trainer.history],
         "final_loss": trainer.history[-1].loss,
+        "state_bytes": sum(int(x.nbytes)
+                           for x in jax.tree.leaves(trainer.state)),
+        "mirror_bytes": snaps.mirror_bytes,
         "scheduler": dict(trainer.sched.stats),
         "store": dict(store.stats),
         "alive_workers": sum(w.alive for w in trainer.workers.values()),

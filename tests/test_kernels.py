@@ -123,6 +123,52 @@ def test_delta_roundtrip_bit_exact(dtype, shape):
     assert np.array_equal(bitmap, b2) and np.array_equal(tiles, t2)
 
 
+def _changed_tiles(nblk: int, pattern: str) -> np.ndarray:
+    if pattern == "none":
+        return np.zeros(0, np.int64)
+    if pattern == "all":
+        return np.arange(nblk)
+    return np.unique(np.r_[0, nblk - 1, np.arange(3, nblk, 7)])
+
+
+# tile counts: one tile, 129 (not a multiple of 8 or 128), exactly 256,
+# and 1025 (one past a full (8, 128) flag block; interpret mode slows
+# down with size, so that one runs a single pattern)
+BITMAP_CASES = [(n, p) for n in (1, 129, 256)
+                for p in ("none", "some", "all")] + [(1025, "some")]
+
+
+@pytest.mark.parametrize("nblk,pattern", BITMAP_CASES)
+def test_delta_bitmap_layout_matches_ref(nblk, pattern):
+    from repro.kernels.delta_encode.kernel import (TILE, changed_bitmap,
+                                                   delta_encode,
+                                                   fused_delta_records)
+    from repro.kernels.delta_encode.ref import fused_records_ref
+    rng = np.random.default_rng(nblk)
+    old = rng.standard_normal(nblk * TILE - 5).astype(np.float32)
+    new = old.copy()
+    for t in _changed_tiles(nblk, pattern):
+        j = min(t * TILE + int(rng.integers(TILE)), old.size - 1)
+        new[j] = -new[j]
+    want_bm, want_tiles = fused_records_ref(old, new)
+    assert want_bm.size == nblk
+    assert int(want_bm.sum()) == len(_changed_tiles(nblk, pattern))
+
+    bm, tiles, n = fused_delta_records(old, new, interpret=True)
+    k = int(want_bm.sum())
+    np.testing.assert_array_equal(np.asarray(bm), want_bm)
+    np.testing.assert_array_equal(np.asarray(tiles)[:k], want_tiles)
+    assert int(n) == old.size
+
+    probe, _ = changed_bitmap(old, new, interpret=True)
+    np.testing.assert_array_equal(np.asarray(probe), want_bm)
+
+    delta, flags, _ = delta_encode(old, new, interpret=True)
+    np.testing.assert_array_equal(np.asarray(flags), want_bm)
+    np.testing.assert_array_equal(np.asarray(delta)[want_bm.astype(bool)],
+                                  want_tiles)
+
+
 def test_delta_unchanged_is_empty():
     x = np.ones(30_000, np.float32)
     tiles, bitmap, _ = diff_blocks(x, x.copy(), mode="interpret")

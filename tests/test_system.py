@@ -133,3 +133,62 @@ def test_elastic_respawn_keeps_training(setup):
     tr.respawn = respawn
     hist = tr.run(2)
     assert len(hist) == 2 and len(spawned) >= 1
+
+
+# ---------------------------------------------------------------------------
+# the launcher: depth-only cut of a published config, resume through main
+# ---------------------------------------------------------------------------
+WIDTHS = ("d_model", "n_heads", "n_kv_heads", "d_ff", "vocab_size",
+          "head_dim", "moe", "ssm")
+
+
+def test_full_preset_layers_cuts_depth_only():
+    from repro.launch.train import build_arch
+    pub = get_arch("granite-3-2b")
+    cfg, cuts = build_arch("granite-3-2b", "full", layers=1)
+    assert cfg.n_layers == 1 and cuts == {"n_layers": [1, pub.n_layers]}
+    assert all(getattr(cfg, w) == getattr(pub, w) for w in WIDTHS)
+    assert build_arch("granite-3-2b", "full") == (pub, {})
+    with pytest.raises(ValueError):
+        build_arch("granite-3-2b", "smoke", layers=1)
+    with pytest.raises(ValueError):
+        build_arch("granite-3-2b", "full", layers=pub.n_layers + 1)
+
+
+@pytest.fixture
+def cache_config(monkeypatch):
+    """Give the compile-cache setting back as it was after the test."""
+    was = jax.config.jax_compilation_cache_dir
+    yield monkeypatch
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_dir_env_then_fixed_checkout_path(cache_config,
+                                                        tmp_path):
+    from repro.launch import jaxcache
+    cache_config.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jaxcache.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    cache_config.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = jaxcache.CHECKOUT / ".jax_cache"
+    assert jaxcache.use_compile_cache() == str(fixed)
+    assert jax.config.jax_compilation_cache_dir == str(fixed)
+    assert (jaxcache.CHECKOUT / "src" / "repro").is_dir()
+    ignored = (jaxcache.CHECKOUT / ".gitignore").read_text().splitlines()
+    assert ".jax_cache/" in ignored
+
+
+def test_train_main_resume_matches_uninterrupted(cache_config, tmp_path):
+    from repro.launch import train
+    cache_config.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    args = ["--seq", "16", "--batch", "2", "--snapshot-every", "1",
+            "--async-writer", "--seed", "0"]
+    full = train.main(args + ["--steps", "4",
+                              "--outdir", str(tmp_path / "a")])
+    train.main(args + ["--steps", "2", "--outdir", str(tmp_path / "b")])
+    resumed = train.main(args + ["--steps", "2", "--resume",
+                                 "--outdir", str(tmp_path / "b")])
+    assert resumed["losses"] == full["losses"][2:]
+    assert full["reduced"] == {} and len(full["step_s"]) == 4
+    assert full["snapshot_writer"]["written"] == 4
+    assert 0 < full["state_bytes"] <= full["mirror_bytes"]

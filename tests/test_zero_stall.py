@@ -152,6 +152,26 @@ def test_probe_launches_o_buckets_not_o_leaves():
     reset_kernel_stats()
 
 
+@pytest.mark.parametrize("mode,extra_dtype,want_ref", [
+    ("interpret", None, 0),           # every leaf on the kernel
+    ("interpret", np.float64, 2),     # f64 leaf: seed + diff on numpy
+    ("ref", None, 2),                 # the oracle itself: seed + diff
+])
+def test_ref_passes_count_numpy_fallback(mode, extra_dtype, want_ref):
+    rng = np.random.default_rng(21)
+    tree = {"a": rng.standard_normal(9000).astype(np.float32)}
+    if extra_dtype is not None:
+        tree["b"] = rng.standard_normal(100).astype(extra_dtype)
+    mirror = DeviceMirror()
+    reset_kernel_stats()
+    probe_leaves(tree, mode=mode, mirror=mirror)
+    probe_leaves({k: v + 1 for k, v in tree.items()}, mode=mode,
+                 mirror=mirror)
+    assert KERNEL_STATS["ref_passes"] == want_ref
+    assert KERNEL_STATS["launches"] == len(tree)   # one diff per bucket
+    reset_kernel_stats()
+
+
 def test_probe_identity_fast_path_skips_launch_for_immutable():
     rng = np.random.default_rng(14)
     frozen = {k: jnp.asarray(v) for k, v in _tree(rng).items()}
