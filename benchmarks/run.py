@@ -11,7 +11,7 @@ import time
 
 def main() -> None:
     from benchmarks import (edge_egress, fig3_overhead, fig4_sprint_pcor,
-                            replica_failover, roofline, server_throughput,
+                            replica_failover, server_throughput,
                             table2_snapshots, telemetry_overhead)
 
     sections = [
@@ -21,7 +21,6 @@ def main() -> None:
         ("server (§IV-C throughput)", server_throughput.run),
         ("replica (fan-out + failover)", replica_failover.run),
         ("edge (discovery + cache egress)", edge_egress.run),
-        ("roofline (dry-run derived)", roofline.run),
         ("telemetry (tracing overhead)", telemetry_overhead.run),
     ]
     print("name,us_per_call,derived")

@@ -20,6 +20,10 @@ pins both claims to numbers CI can gate:
     PYTHONPATH=src:. python -m benchmarks.telemetry_overhead \
         --json /tmp/tel.json
     PYTHONPATH=src:. python -m benchmarks.check_regression /tmp/tel.json
+
+``--spans N`` instead prints the cost of one timed span (``Telemetry
+.span``: two clock reads, a profiler annotation and a ring append) over N
+nested pairs, with the profiler off and then recording.
 """
 from __future__ import annotations
 
@@ -85,6 +89,32 @@ def run_curve(clients: int = 2000, samples: int = 400) -> dict:
             "rows": rows, "overhead_ratio": ratio}
 
 
+def span_cost_ns(n: int, profiler: bool) -> float:
+    """Nanoseconds per span, over ``n`` pairs of a span nested in another
+    (as ``validate.copy`` sits in ``validate``), on an isolated hub."""
+    import tempfile
+
+    import jax
+    hub = tlm.Telemetry()
+    with hub.span("warm"):
+        pass
+    tmp = tempfile.TemporaryDirectory() if profiler else None
+    if profiler:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(tmp.name, profiler_options=opts)
+    t0 = time.perf_counter_ns()
+    for i in range(n):
+        with hub.span("outer", step=i):
+            with hub.span("inner"):
+                pass
+    dt = time.perf_counter_ns() - t0
+    if profiler:
+        jax.profiler.stop_trace()
+        tmp.cleanup()
+    return dt / (2 * n)
+
+
 def run(tiny: bool = True) -> list[str]:
     """Registry entry point (benchmarks/run.py): CSV lines."""
     curve = run_curve()
@@ -103,7 +133,14 @@ def main(argv=None) -> int:
     ap.add_argument("--samples", type=int, default=400)
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write the machine-readable result here")
+    ap.add_argument("--spans", type=int, default=0, metavar="N",
+                    help="measure the cost of a span instead, over N pairs")
     args = ap.parse_args(argv)
+    if args.spans:
+        for profiler in (False, True):
+            print(f"  span ns, profiler {'on ' if profiler else 'off'}: "
+                  f"{span_cost_ns(args.spans, profiler):.1f}")
+        return 0
     curve = run_curve(clients=args.clients, samples=args.samples)
     for r in curve["rows"]:
         print(f"  {r['name']:9s} p50 {r['p50_us']:8.2f}us  "

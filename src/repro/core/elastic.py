@@ -30,10 +30,19 @@ from repro.core.uplink import DEFAULT_UPLINK_CHUNK, UplinkEncoder
 from repro.data.pipeline import Cursor, DataConfig, TokenStream
 
 
-def grad_hash(tree) -> str:
+def grad_hash(tree, *, unit=None,
+              telemetry: Optional[tlm.Telemetry] = None) -> str:
+    """blake2b of the gradient's bytes, leaf by leaf: each leaf is copied
+    to the host (``validate.copy`` span), then hashed (``validate.digest``)."""
+    tel = tlm.resolve(telemetry)
     h = hashlib.blake2b()
-    for leaf in jax.tree.leaves(tree):
-        h.update(memoryview(np.ascontiguousarray(np.asarray(leaf))).cast("B"))
+    with tel.span("validate", unit=unit):
+        for leaf in jax.tree.leaves(tree):
+            with tel.span("validate.copy"):
+                host = np.ascontiguousarray(np.asarray(leaf))
+            with tel.span("validate.digest"):
+                h.update(memoryview(host).cast("B"))
+            del host
     return h.hexdigest()
 
 
@@ -159,7 +168,9 @@ class VolunteerTrainer:
         self.tel = tlm.resolve(telemetry)
         scope = self.tel.scope("trainer")
         self.tmetrics = scope.counters("uplink_dense", "uplink_moved",
-                                       "uplink_dedup", "folds")
+                                       "uplink_dedup", "folds",
+                                       "validated_results",
+                                       "validated_bytes")
         self.tstats = scope.view()
         # unit -> {worker: (moved, dedup)} awaiting quorum validation
         self._pending_credit: Dict[int, Dict[str, tuple]] = {}
@@ -196,16 +207,25 @@ class VolunteerTrainer:
     def _execute_unit(self, worker: SimWorker, unit) -> None:
         batch = self.stream.batch(unit.payload["batch_index"])
         sub = {k: v for k, v in batch.items()}
-        loss, grads = self.grad_fn(self.state.params, sub)
+        with self.tel.span("grad_step", unit=unit.unit_id):
+            loss, grads = self.grad_fn(self.state.params, sub)
         if self.uplink:
             self._execute_unit_uplink(worker, unit, float(loss), grads)
             return
-        h = grad_hash(grads)
+        h = self._validate(unit, grads)
         if worker.rng.random() < worker.corrupt_prob:
             h = "corrupt-" + h[:16]        # wrong result; quorum rejects
         else:
             self._grad_cache[h] = (float(loss), grads)
         self.sched.report(worker.worker_id, unit.unit_id, h)
+
+    def _validate(self, unit, grads) -> str:
+        """Hash one result for quorum validation, counting it."""
+        h = grad_hash(grads, unit=unit.unit_id, telemetry=self.tel)
+        self.tmetrics.validated_results.inc()
+        self.tmetrics.validated_bytes.inc(
+            sum(int(x.nbytes) for x in jax.tree.leaves(grads)))
+        return h
 
     def _execute_unit_uplink(self, worker: SimWorker, unit,
                              loss: float, grads) -> None:
@@ -219,7 +239,7 @@ class VolunteerTrainer:
         wid = worker.worker_id
         comp, _ = grad_compress.compress(grads, grad_compress.init_error(grads))
         grads = grad_compress.decompress(comp, grads)
-        h = grad_hash(grads)
+        h = self._validate(unit, grads)
         if worker.rng.random() < worker.corrupt_prob:
             h = "corrupt-" + h[:16]        # wrong result; quorum rejects
         else:
@@ -293,6 +313,10 @@ class VolunteerTrainer:
         return losses, jax.tree.map(lambda g: g / self.micro_batches, grads)
 
     def round(self, step: int) -> RoundStats:
+        with self.tel.span("round", step=step):
+            return self._round(step)
+
+    def _round(self, step: int) -> RoundStats:
         base_index = self.cursor.next_index
         for k in range(self.micro_batches):
             self.sched.submit(step * self.micro_batches + k,
@@ -340,7 +364,10 @@ class VolunteerTrainer:
         drained = self.sched.drain_completed()
         self._settle_uplink_credit(drained)
         self._completed.update(drained)
-        losses, grads = self._fold_round(step)
+        # the span closes after the call has returned, so it also holds
+        # the release of the gradients the fold's frame drops
+        with self.tel.span("fold"):
+            losses, grads = self._fold_round(step)
         if self.compress_grads:
             from repro.optim import grad_compress
             if self._compress_err is None:
@@ -348,21 +375,21 @@ class VolunteerTrainer:
             comp, self._compress_err = grad_compress.compress(
                 grads, self._compress_err)
             grads = grad_compress.decompress(comp, grads)
-        self.state = self.apply_fn(self.state, grads)
+        with self.tel.span("apply"):
+            self.state = self.apply_fn(self.state, grads)
         del grads            # the snapshot probe below needs the room
 
         snapshot_stall_ms, snapshot_bytes = 0.0, 0
         if (self.snapshots is not None and self.snapshot_every
                 and (step + 1) % self.snapshot_every == 0):
-            import time as _time
-            t0 = _time.perf_counter()
             # async managers: plan synchronously, persist in the background
             # — the round pays only the device probe (+ any backpressure)
-            res = self.snapshots.snapshot(
-                self.state, step=step,
-                aux={"cursor": self.cursor.to_state(), "round": step},
-                block=not getattr(self.snapshots, "is_async", False))
-            snapshot_stall_ms = (_time.perf_counter() - t0) * 1e3
+            with self.tel.span("snapshot") as sp:
+                res = self.snapshots.snapshot(
+                    self.state, step=step,
+                    aux={"cursor": self.cursor.to_state(), "round": step},
+                    block=not getattr(self.snapshots, "is_async", False))
+            snapshot_stall_ms = sp.ms
             info = res if not isinstance(res, Future) \
                 else self.snapshots.last_info
             if info is not None:

@@ -20,15 +20,15 @@ anything crosses the device→host boundary:
   the device probe + changed-tile transfer (``probe_leaves``); chunk
   compaction, hashing, RLE, ``put_delta`` and deferred ``max_chain``
   rebase run on a background ``SnapshotWriter`` behind a bounded queue,
-  so the trainer's stall is the probe and nothing else
-  (``SnapshotInfo.stall_ms`` vs ``writer_ms``).  Plans are self-contained
-  (they carry the changed tiles + bitmap, or the full base image); the
-  writer keeps its OWN host image per tensor and advances it serially, so
-  writer and planner share no mutable state.  A half-written snapshot
-  stays invisible: the manifest registers only after every object landed,
-  and a write failure poisons the queue — the next snapshot re-bases from
-  a fresh base image, exactly the ``_mirror.clear()`` invariant of the
-  inline path.
+  so the trainer's stall is the probe and nothing else (the caller's
+  ``snapshot`` span vs the writer's ``writer.write``).  Plans are
+  self-contained (they carry the changed tiles + bitmap, or the full
+  base image); the writer keeps its OWN host image per tensor and
+  advances it serially, so writer and planner share no mutable state.
+  A half-written snapshot stays invisible: the manifest registers only
+  after every object landed, and a write failure poisons the queue — the
+  next snapshot re-bases from a fresh base image, exactly the
+  ``_mirror.clear()`` invariant of the inline path.
 * **Manifest v2** — each ``TensorEntry`` records per-block refs that are
   either raw hashes or ``"d:"`` delta refs.  v1 manifests (``hashes``)
   remain readable, so old snapshot directories restore unchanged.
@@ -59,6 +59,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.core import telemetry as tlm
 from repro.core.chunkstore import ChunkStore, sha256
 from repro.core.writer import SnapshotWriter
 from repro.kernels.delta_encode.ops import (DeviceMirror, chunk_records,
@@ -131,15 +132,11 @@ class SnapshotInfo:
     snapshot_id: str
     step: int
     kind: str
-    wall_s: float
     new_bytes: int        # differencing-image cost (changed blocks)
     dedup_bytes: int      # blocks reused from the chain
     total_bytes: int      # logical state size
     changed_chunks: int = 0
     reused_chunks: int = 0
-    stall_ms: float = 0.0     # trainer-visible time (plan [+ write inline])
-    plan_ms: float = 0.0      # device probe + changed-tile transfer
-    writer_ms: float = 0.0    # background chunk/hash/RLE/store/rebase time
 
 
 @dataclass
@@ -171,7 +168,7 @@ class SnapshotManager:
                  delta_mode: str = "auto",
                  telemetry=None):
         self.store = store
-        self.telemetry = telemetry
+        self.tel = tlm.resolve(telemetry)
         self.root = Path(root) if root is not None else None
         if self.root is not None:
             (self.root / "manifests").mkdir(parents=True, exist_ok=True)
@@ -188,8 +185,8 @@ class SnapshotManager:
         self.delta_mode = delta_mode
         self.manifests: Dict[str, Manifest] = {}
         self.order: List[str] = []                 # snapshot chain
-        self._writer = SnapshotWriter(self._write_bg, depth=writer_depth,
-                                      telemetry=telemetry) \
+        self._writer = SnapshotWriter(self._write_inner, depth=writer_depth,
+                                      telemetry=self.tel) \
             if async_mode else None
         self._futures: deque[Future] = deque()
         self.last_info: Optional[SnapshotInfo] = None
@@ -224,26 +221,24 @@ class SnapshotManager:
         write's Future immediately — the caller's stall is the probe plus
         queue backpressure, nothing else."""
         self._reap()             # surface any finished/failed async write
-        t0 = time.time()
-        tp = time.perf_counter()
         try:
-            plan = self._plan_state(state)
+            with self.tel.span("snapshot.plan", step=step):
+                plan = self._plan_state(state)
         except BaseException:
             # a partial plan has already advanced some tensors' mirrors
             # while _prev_refs still points at the old chunks; drop both so
             # the next snapshot re-bases instead of recording stale refs
             self._poison()
             raise
-        plan_ms = (time.perf_counter() - tp) * 1e3
         if self._writer is not None:
             try:
-                fut = self._writer.submit(plan, step, aux or {}, t0, plan_ms)
+                fut = self._writer.submit(plan, step, aux or {}, step=step)
             except BaseException:
                 self._poison()
                 raise
             self._futures.append(fut)
             return self.wait() if block else fut
-        return self._write_sync(plan, step, aux or {}, t0, plan_ms)
+        return self._write_sync(plan, step, aux or {})
 
     def wait(self) -> Optional[SnapshotInfo]:
         """Drain pending background writes; returns the last SnapshotInfo.
@@ -319,7 +314,8 @@ class SnapshotManager:
 
     def _plan_base(self, key: str, leaf) -> _TensorPlan:
         shape, dtype = tuple(leaf.shape), str(leaf.dtype)
-        host = np.ascontiguousarray(np.asarray(leaf))
+        with self.tel.span("snapshot.d2h"):
+            host = np.ascontiguousarray(np.asarray(leaf))
         if host.shape != shape:
             host = host.reshape(shape)   # ascontiguousarray 0-d -> 1-d
         if host is leaf or host.base is not None:
@@ -327,9 +323,9 @@ class SnapshotManager:
         return _TensorPlan(key, shape, dtype, host.nbytes, base=host)
 
     # ------------------------------------------------------------------
-    def _write_sync(self, plan, step, aux, t0, plan_ms) -> SnapshotInfo:
+    def _write_sync(self, plan, step, aux) -> SnapshotInfo:
         try:
-            info = self._write_inner(plan, step, aux, t0)
+            info = self._write_inner(plan, step, aux)
         except BaseException:
             # the probe already swapped the device mirror forward; a
             # half-written store would make the NEXT diff record stale
@@ -337,21 +333,14 @@ class SnapshotManager:
             # full base image.
             self._poison()
             raise
-        info.plan_ms = plan_ms
-        info.stall_ms = info.wall_s * 1e3    # inline: the trainer paid it all
         self.last_info = info
         return info
 
-    def _write_bg(self, plan, step, aux, t0, plan_ms) -> SnapshotInfo:
-        tw = time.perf_counter()
-        info = self._write_inner(plan, step, aux, t0)
-        info.plan_ms = plan_ms
-        info.stall_ms = plan_ms              # trainer paid only the plan
-        info.writer_ms = (time.perf_counter() - tw) * 1e3
-        return info
-
-    def _write_inner(self, plan: List[_TensorPlan], step: int, aux: dict,
-                     t0: float) -> SnapshotInfo:
+    def _write_inner(self, plan: List[_TensorPlan], step: int,
+                     aux: dict) -> SnapshotInfo:
+        """Persist one plan: per tensor, fold the probe's tiles into the
+        host image (``writer.records`` span), then store its changed
+        chunks (``writer.put``); then register the manifest."""
         before_put = self.store.stats["put_bytes"]
         before_dedup = self.store.stats["dedup_bytes"]
         cb = self.store.chunk_bytes
@@ -365,7 +354,8 @@ class SnapshotManager:
                 total += p.nbytes
                 if p.base is not None:
                     flat = np.asarray(p.base).reshape(-1).view(np.uint8)
-                    refs = self.store.put_buffer(memoryview(flat))
+                    with self.tel.span("writer.put"):
+                        refs = self.store.put_buffer(memoryview(flat))
                     changed += len(refs)
                     self._mirror[p.key] = flat
                 else:
@@ -375,24 +365,26 @@ class SnapshotManager:
                     records: Dict[int, bytes] = {}
                     new_flat = None
                     if p.bitmap is not None and p.bitmap.any():
-                        records, new_flat = chunk_records(
-                            self._mirror[p.key], p.tiles, p.bitmap,
-                            p.nbytes, cb)
+                        with self.tel.span("writer.records"):
+                            records, new_flat = chunk_records(
+                                self._mirror[p.key], p.tiles, p.bitmap,
+                                p.nbytes, cb)
                     refs = []
-                    for ci, pref in enumerate(prev_refs):
-                        xor = records.get(ci)
-                        if xor is None:
-                            refs.append(pref)
-                            reused += 1
-                            reused_bytes += max(
-                                0, min((ci + 1) * cb, p.nbytes) - ci * cb)
-                        else:
-                            cs = ci * cb
-                            ce = min(cs + cb, p.nbytes)
-                            refs.append(self.store.put_delta(
-                                pref, xor,
-                                full_bytes=new_flat[cs:ce].tobytes()))
-                            changed += 1
+                    with self.tel.span("writer.put"):
+                        for ci, pref in enumerate(prev_refs):
+                            xor = records.get(ci)
+                            if xor is None:
+                                refs.append(pref)
+                                reused += 1
+                                reused_bytes += max(
+                                    0, min((ci + 1) * cb, p.nbytes) - ci * cb)
+                            else:
+                                cs = ci * cb
+                                ce = min(cs + cb, p.nbytes)
+                                refs.append(self.store.put_delta(
+                                    pref, xor,
+                                    full_bytes=new_flat[cs:ce].tobytes()))
+                                changed += 1
                     if new_flat is not None:
                         self._mirror[p.key] = new_flat
                 tensors[p.key] = TensorEntry(p.shape, p.dtype, refs)
@@ -413,7 +405,6 @@ class SnapshotManager:
         self.gc() if self.auto_gc else self._trim_manifests()
         return SnapshotInfo(
             snapshot_id=sid, step=step, kind=man.kind,
-            wall_s=time.time() - t0,
             new_bytes=self.store.stats["put_bytes"] - before_put,
             dedup_bytes=self.store.stats["dedup_bytes"] - before_dedup,
             total_bytes=total,

@@ -25,7 +25,17 @@ by every layer built since PR 1:
   JSONL on fault or on demand;
 * :func:`trace_reduce` — the post-mortem tool: reconstructs per-unit
   causal chains from a dump and flags anomalies (unclosed spans, quorum
-  without a lease, reissue storms, reissues with no recorded cause).
+  without a lease, reissue storms, reissues with no recorded cause);
+* **timed spans** — :meth:`Telemetry.span` brackets the work of one
+  layer (``round``, ``validate``, ``snapshot``, ``writer.write``, ...)
+  on ``time.perf_counter_ns``, the profiler's host clock, and enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+  shows each span beside the device operations it launched.  Spans nest
+  on a per-thread stack, carry the round (``step``) and work unit they
+  belong to, and may name a ``cause`` span on another thread (a
+  background write names the snapshot that queued it).  They are always
+  recorded, into a bounded ring of their own (``Telemetry.spans``); they
+  never enter the event ring, whose stream stays on the hub's clock.
 
 The hub is process-wide by default (module-level instance, so components
 constructed without an explicit ``telemetry=`` all share it) but fully
@@ -81,7 +91,9 @@ or ``unclosed_span`` had it never reached quorum.
 """
 from __future__ import annotations
 
+import itertools
 import json
+import threading
 import time
 from bisect import bisect_left
 from collections import deque
@@ -91,9 +103,9 @@ from types import SimpleNamespace
 from typing import Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricScope", "StatsView",
+    "Counter", "Gauge", "Histogram", "MetricScope", "Span", "StatsView",
     "Telemetry", "TraceReport", "get_default", "set_default", "resolve",
-    "trace_reduce", "TIME_BUCKETS_S", "SIZE_BUCKETS",
+    "span", "trace_reduce", "TIME_BUCKETS_S", "SIZE_BUCKETS",
 ]
 
 # latency buckets (seconds): 1us .. 1s, the dispatch/probe range
@@ -248,14 +260,98 @@ class MetricScope:
         return StatsView(self._scalars)
 
 
+# span ids are unique per process, so a parent or cause may sit on another
+# hub or thread; the open spans of each thread form its own stack
+_SPAN_IDS = itertools.count(1)
+_OPEN = threading.local()
+_TRACE_ME = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation``; jax is imported on first use."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME(name)
+
+
+def _open_spans() -> list:
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+class Span:
+    """One timed piece of work; a context manager.
+
+    ``step`` is inherited from the enclosing span when not given, so every
+    span of one round carries that round.  ``parent`` is the id of the
+    enclosing span on the same thread (0 at the top), ``cause`` the id of a
+    span (any thread) that asked for this work.  ``start_ns``/``end_ns``
+    are ``time.perf_counter_ns`` readings; ``end_ns`` is None while open.
+    The record enters the hub's ring when the span opens, so the ring
+    holds spans in the order they started."""
+
+    __slots__ = ("id", "name", "step", "unit", "parent", "cause", "thread",
+                 "start_ns", "end_ns", "_hub", "_ann")
+
+    def __init__(self, hub: "Telemetry", name: str, step=None, unit=None,
+                 cause=None):
+        self._hub = hub
+        self.name = name
+        self.step = step
+        self.unit = unit
+        self.cause = cause
+        self.id = self.parent = 0
+        self.thread = None
+        self.start_ns = self.end_ns = None
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        ann = _annotation(self.name)
+        stack = _open_spans()
+        if stack:
+            top = stack[-1]
+            self.parent = top.id
+            if self.step is None:
+                self.step = top.step
+        self.id = next(_SPAN_IDS)
+        self.thread = threading.get_ident()
+        stack.append(self)
+        self._hub.spans.append(self)
+        ann.__enter__()
+        self._ann = ann
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        _open_spans().pop()
+        return False
+
+    @property
+    def ms(self) -> float:
+        """Duration in milliseconds (of a closed span)."""
+        return (self.end_ns - self.start_ns) * 1e-6
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name}, id={self.id}, step={self.step}, "
+                f"parent={self.parent})")
+
+
 class Telemetry:
-    """The hub: scope factory, event recorder, exporters.
+    """The hub: scope factory, event recorder, span recorder, exporters.
 
     ``clock`` is any zero-arg callable returning a float timestamp —
     pass the component graph's shared ``SimClock`` for deterministic
     traces (the default, wall time, is for live runs where byte
     identity does not matter).  ``tracing`` gates the recorder; metrics
-    always count (they are the ``.stats`` backing store)."""
+    always count (they are the ``.stats`` backing store), and so do spans
+    (into ``spans``, a ring of ``capacity`` records beside the events')."""
 
     def __init__(self, *, clock=None, tracing: bool = False,
                  capacity: int = DEFAULT_CAPACITY):
@@ -263,6 +359,7 @@ class Telemetry:
         self.tracing = bool(tracing)
         self.capacity = int(capacity)
         self.events: deque = deque(maxlen=self.capacity)
+        self.spans: deque = deque(maxlen=self.capacity)
         self._seq = 0
         self._scopes: List[MetricScope] = []
         self._scope_counts: Dict[str, int] = {}
@@ -301,6 +398,12 @@ class Telemetry:
 
     def reset_events(self) -> None:
         self.events.clear()
+
+    # ---------------- spans ----------------
+    def span(self, name: str, *, step=None, unit=None, cause=None) -> Span:
+        """A timed span recorded into this hub's span ring; use it as a
+        context manager, and read the duration from its ``ms`` after."""
+        return Span(self, name, step, unit, cause)
 
     # ---------------- exporters ----------------
     def event_lines(self) -> List[str]:
@@ -370,6 +473,11 @@ def set_default(tel: Telemetry) -> Telemetry:
 def resolve(tel: Optional[Telemetry]) -> Telemetry:
     """Component constructors: explicit hub wins, else the default."""
     return tel if tel is not None else _DEFAULT
+
+
+def span(name: str, *, step=None, unit=None, cause=None) -> Span:
+    """A span on the process default hub (see :meth:`Telemetry.span`)."""
+    return _DEFAULT.span(name, step=step, unit=unit, cause=cause)
 
 
 # ---------------- trace_reduce: post-mortem causal chains ----------------

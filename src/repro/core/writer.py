@@ -12,7 +12,10 @@ behind a bounded queue:
   between the two threads beyond the queue.
 * **Backpressure** — the queue is bounded (``depth``); when the writer
   falls behind, ``submit`` blocks and the blocked time is accounted as
-  ``backpressure_ms`` (it is trainer-visible stall, not hidden).
+  ``backpressure_ms`` (it is trainer-visible stall, not hidden), from the
+  ``writer.submit`` span; ``write_ms`` comes from the ``writer.write``
+  span, which carries the submitter's step and names the span that
+  submitted it as its ``cause``.
 * **Fail-stop** — a failed write poisons the writer: every queued and
   later submission fails fast with the original error chained, because a
   write after a failed write would record delta refs against parents that
@@ -27,7 +30,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from concurrent.futures import Future
 from typing import Callable, Optional
 
@@ -61,17 +63,19 @@ class SnapshotWriter:
         self._thread.start()
 
     # ------------------------------------------------------------------
-    def submit(self, *args) -> Future:
-        """Enqueue one write; blocks only when the bounded queue is full
-        (counted as ``backpressure_ms`` — real trainer-visible stall)."""
+    def submit(self, *args, step=None) -> Future:
+        """Enqueue one write of ``write_fn(*args)``; blocks only when the
+        bounded queue is full (counted as ``backpressure_ms`` — real
+        trainer-visible stall).  ``step`` labels the write's spans; by
+        default the enclosing span's."""
         if self.error is not None:
             raise WriterPoisonedError(
                 "snapshot writer poisoned by an earlier failure"
             ) from self.error
         fut: Future = Future()
-        t0 = time.perf_counter()
-        self._q.put((fut, args))
-        self.metrics.backpressure_ms.inc((time.perf_counter() - t0) * 1e3)
+        with self.tel.span("writer.submit", step=step) as sp:
+            self._q.put((fut, args, sp.step, sp.parent or None))
+        self.metrics.backpressure_ms.inc(sp.ms)
         self.metrics.submitted.inc()
         return fut
 
@@ -80,25 +84,25 @@ class SnapshotWriter:
             item = self._q.get()
             if item is _STOP:
                 return
-            fut, args = item
+            fut, args, step, cause = item
             if self.error is not None:
                 # fail-stop: later writes would chain refs onto parents
                 # that never landed
                 fut.set_exception(WriterPoisonedError(
                     "snapshot writer poisoned by an earlier failure"))
                 continue
-            t0 = time.perf_counter()
-            try:
-                res = self.write_fn(*args)
-            except BaseException as exc:  # noqa: BLE001 — forwarded via future
-                self.error = exc
-                self.metrics.failed.inc()
-                fut.set_exception(exc)
-            else:
-                self.metrics.written.inc()
-                fut.set_result(res)
-            finally:
-                self.metrics.write_ms.inc((time.perf_counter() - t0) * 1e3)
+            with self.tel.span("writer.write", step=step,
+                               cause=cause) as sp:
+                try:
+                    res = self.write_fn(*args)
+                except BaseException as exc:  # noqa: BLE001 — via future
+                    self.error = exc
+                    self.metrics.failed.inc()
+                    fut.set_exception(exc)
+                else:
+                    self.metrics.written.inc()
+                    fut.set_result(res)
+            self.metrics.write_ms.inc(sp.ms)
 
     # ------------------------------------------------------------------
     def reset(self) -> None:

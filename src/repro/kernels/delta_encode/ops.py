@@ -29,12 +29,15 @@ reference swap, not copy), eliminating the per-probe H→D re-upload of the
 host mirror.  The numpy ``ref`` mode mirrors every kernel bit-for-bit
 (used on hosts without a TPU runtime; the default when jax is on CPU).
 
-``KERNEL_STATS`` counts launches and streamed bytes (ref-mode passes count
-as one launch each) — ``benchmarks/roofline.py`` reads it to prove
-launches-per-snapshot is O(buckets) and the probe runs at memory bandwidth.
+``KERNEL_STATS`` is the read-only view of the ``delta_encode`` telemetry
+scope: launches and streamed bytes (ref-mode passes count as one launch
+each), so launches-per-snapshot can be checked to be O(buckets).
 ``ref_passes`` separately counts every leaf set the numpy oracle handled
 (ref mode, or a kernel-mode leaf whose dtype the kernel cannot bitcast),
 so a run that was meant to use the compiled kernel can assert it did.
+Each copy of a probe's bitmap, changed tiles or ref-mode leaf image to
+the host runs under a ``delta_encode.d2h`` span; the caller's own spans
+say whose copy it is (a snapshot's plan, or the uplink's differ).
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from repro.core import telemetry as tlm
 from repro.kernels.delta_encode.kernel import (LANE, SUB, TILE, as_i32_tiles,
                                                changed_bitmap, delta_apply,
                                                delta_encode,
@@ -59,24 +63,34 @@ KERNEL_DTYPES = ("int32", "float32", "bfloat16", "float16", "int16")
 # concatenated per power-of-two size bucket (256 tiles = 8 MiB of state)
 MAX_BUCKET_TILES = 256
 
-# launch/bandwidth accounting for benchmarks/roofline.py; a ref-mode pass
-# over a (concatenated) tile view counts as one launch, and also as one of
-# ``ref_passes`` (numpy-oracle passes, seeding ones included)
-KERNEL_STATS = {"launches": 0, "probe_bytes": 0, "d2h_bytes": 0,
-                "ref_passes": 0}
+# launch/bandwidth accounting, on the hub that is the default at import; a
+# ref-mode pass over a (concatenated) tile view counts as one launch, and
+# also as one of ``ref_passes`` (numpy-oracle passes, seeding ones included)
+_SCOPE = tlm.get_default().scope("delta_encode")
+_METRICS = _SCOPE.counters("launches", "probe_bytes", "d2h_bytes",
+                           "ref_passes")
+KERNEL_STATS = _SCOPE.view()
 
 
 def reset_kernel_stats() -> dict:
+    """Zero the counters; -> their values before."""
     prev = dict(KERNEL_STATS)
-    for k in KERNEL_STATS:
-        KERNEL_STATS[k] = 0
+    for c in vars(_METRICS).values():
+        c.value = 0
     return prev
 
 
 def _count_launch(tile_bytes: int, d2h: int) -> None:
-    KERNEL_STATS["launches"] += 1
-    KERNEL_STATS["probe_bytes"] += 2 * tile_bytes   # streams old + new
-    KERNEL_STATS["d2h_bytes"] += d2h
+    _METRICS.launches.inc()
+    _METRICS.probe_bytes.inc(2 * tile_bytes)   # streams old + new
+    _METRICS.d2h_bytes.inc(d2h)
+
+
+def _to_host(x) -> np.ndarray:
+    """A probe's output (or a leaf) copied to the host; a device array's
+    copy first waits for the launch that writes it."""
+    with tlm.span("delta_encode.d2h"):
+        return np.asarray(x)
 
 
 def _resolve_mode(mode: str) -> str:
@@ -182,7 +196,7 @@ def _fetch_compacted(bitmap: np.ndarray, tiles_dev, tile_bytes: int):
         _count_launch(tile_bytes, bitmap.nbytes)
         return np.zeros((0, SUB, LANE), np.int32)
     padded = min(1 << (k - 1).bit_length(), bitmap.size)
-    tiles = np.asarray(tiles_dev[:padded])[:k]
+    tiles = _to_host(tiles_dev[:padded])[:k]
     _count_launch(tile_bytes, bitmap.nbytes + padded * TILE_BYTES)
     return tiles
 
@@ -220,7 +234,7 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
         else int(np.asarray(old).nbytes)
     if mode == "ref":
         bitmap, tiles = fused_records_ref(old, new)
-        KERNEL_STATS["ref_passes"] += 1
+        _METRICS.ref_passes.inc()
         _count_launch(bitmap.size * TILE_BYTES, 0)
     elif fused:
         interpret = (mode == "interpret")
@@ -232,7 +246,7 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
             import jax
             o32, _ = as_i32_tiles(jax.device_put(old))
         bm, tiles_dev = fused_delta_tiles(o32, n32, interpret=interpret)
-        bitmap = np.asarray(bm)
+        bitmap = _to_host(bm)
         tiles = _fetch_compacted(bitmap, tiles_dev, n32.nbytes)
         if mirror is not None:
             mirror.swap(mirror_key, layout, n32)   # swap, not copy
@@ -242,7 +256,7 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
         interpret = (mode == "interpret")
         old = jax.device_put(old)         # upload the mirror ONCE; both
         bm, _ = changed_bitmap(old, new, interpret=interpret)  # passes reuse
-        bitmap = np.asarray(bm)           # tiny: one i32 per 32 KiB
+        bitmap = _to_host(bm)           # tiny: one i32 per 32 KiB
         idx = np.flatnonzero(bitmap)
         k = idx.size
         tile_bytes = bitmap.size * TILE_BYTES
@@ -256,8 +270,8 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
             padded = 1 << (k - 1).bit_length()
             idx = np.concatenate([idx,
                                   np.full(padded - k, idx[-1], idx.dtype)])
-            tiles = np.asarray(gather_delta(old, new,
-                                            jnp.asarray(idx, jnp.int32)))[:k]
+            tiles = _to_host(gather_delta(old, new,
+                                          jnp.asarray(idx, jnp.int32)))[:k]
             _count_launch(tile_bytes, padded * TILE_BYTES)
     if emit == "tiles":
         return tiles, bitmap, nbytes
@@ -381,7 +395,7 @@ def _probe_slot(key, leaf, meta: tuple, mode: str, mirror: DeviceMirror):
         # same immutable array as last round: unchanged by construction
         return _EMPTY_TILES, np.zeros(ntiles, np.int32), nbytes
     if mode == "ref":
-        KERNEL_STATS["ref_passes"] += 1
+        _METRICS.ref_passes.inc()
         n32 = _ref_tiles(leaf)
         o32 = mirror.get(key, layout)
         mirror.swap(key, layout, n32, (leaf,))
@@ -402,7 +416,7 @@ def _probe_slot(key, leaf, meta: tuple, mode: str, mirror: DeviceMirror):
         return _EMPTY_TILES, np.zeros(0, np.int32), nbytes
     bm, tiles_dev = fused_delta_tiles(o32, n32,
                                       interpret=(mode == "interpret"))
-    bitmap = np.asarray(bm)
+    bitmap = _to_host(bm)
     tiles = _fetch_compacted(bitmap, tiles_dev, int(n32.nbytes))
     return tiles, bitmap, nbytes
 
@@ -431,7 +445,7 @@ def _probe_bucket(bid: int, leaves: list, news: dict, mode: str,
         return {key: (_EMPTY_TILES, np.zeros(nt, np.int32), nb)
                 for key, nb, nt, _ in leaves}
     if mode == "ref":
-        KERNEL_STATS["ref_passes"] += 1
+        _METRICS.ref_passes.inc()
         parts = [_ref_tiles(x) for x in leaf_objs]
         n32 = parts[0] if len(parts) == 1 else np.concatenate(parts)
         o32 = mirror.get(skey, layout)
@@ -450,7 +464,7 @@ def _probe_bucket(bid: int, leaves: list, news: dict, mode: str,
             return {key: None for key, _, _, _ in leaves}
         bm, tiles_dev = fused_delta_tiles(o32, n32,
                                           interpret=(mode == "interpret"))
-        bitmap = np.asarray(bm)
+        bitmap = _to_host(bm)
         tiles = _fetch_compacted(bitmap, tiles_dev, int(n32.nbytes))
     out = {}
     off = pos = 0
@@ -547,7 +561,7 @@ def _diff_bucket(bid: int, leaves: list, olds: dict, news: dict,
         o32 = np.concatenate([_ref_tiles(olds[k]) for k, _, _ in leaves])
         n32 = np.concatenate([_ref_tiles(news[k]) for k, _, _ in leaves])
         bitmap, tiles = fused_tiles_ref(o32, n32)
-        KERNEL_STATS["ref_passes"] += 1
+        _METRICS.ref_passes.inc()
         _count_launch(n32.nbytes, 0)
     else:
         import jax
@@ -562,7 +576,7 @@ def _diff_bucket(bid: int, leaves: list, olds: dict, news: dict,
                 [as_i32_tiles(jax.device_put(olds[k]))[0]
                  for k, _, _ in leaves])
         bm, tiles_dev = fused_delta_tiles(o32, n32, interpret=interpret)
-        bitmap = np.asarray(bm)
+        bitmap = _to_host(bm)
         tiles = _fetch_compacted(bitmap, tiles_dev, int(n32.nbytes))
         if mirror is not None:
             mirror.swap(("bucket", bid), layout, n32)
@@ -582,7 +596,7 @@ def _diff_bucket(bid: int, leaves: list, olds: dict, news: dict,
 def _ref_tiles(x) -> np.ndarray:
     """Numpy mirror of ``as_i32_tiles``: flat i32 view padded to whole
     (8, 1024) tiles."""
-    b = np.ascontiguousarray(np.asarray(x)).reshape(-1).view(np.uint8)
+    b = np.ascontiguousarray(_to_host(x)).reshape(-1).view(np.uint8)
     pad = (-b.size) % (TILE * 4)
     if pad:
         b = np.concatenate([b, np.zeros(pad, np.uint8)])

@@ -156,7 +156,10 @@ def main(argv=None) -> dict:
     grad_fn = jax.jit(jax.value_and_grad(loss_fn))
 
     def apply_fn(state, grads):
-        p, o, _ = adamw.update(oc, grads, state.opt, state.params)
+        # eager AdamW: the span holds the host's dispatch of its per-leaf
+        # programs (the jitted update inside would only trace a span)
+        with tlm.span("optimizer"):
+            p, o, _ = adamw.update(oc, grads, state.opt, state.params)
         return api.TrainState(p, o)
 
     stream = TokenStream(DataConfig(cfg.vocab_size, args.seq, args.batch,
