@@ -68,6 +68,42 @@ def sha256(data: bytes) -> str:
     return hashlib.sha256(data).hexdigest()
 
 
+class Digested:
+    """A private, read-only copy of one chunk's bytes and its sha256 ref.
+
+    ``ChunkStore.put`` keeps the copy as the stored object and does not
+    hash it again.  numpy makes the copy and ``hashlib`` the hash, both
+    with the GIL released, so a writer can prepare chunks on a thread
+    pool.  It reads like the bytes it holds (``len``, indexing, the buffer
+    protocol), so whatever takes ``put_delta``'s buffers takes it too."""
+
+    __slots__ = ("view", "ref")
+
+    def __init__(self, buf):
+        src = np.frombuffer(buf, np.uint8)
+        copy = np.empty_like(src)
+        copy[...] = src
+        copy.flags.writeable = False
+        self.view = memoryview(copy)
+        self.ref = sha256(self.view)
+
+    def __len__(self) -> int:
+        return len(self.view)
+
+    def __getitem__(self, i):
+        return self.view[i]
+
+    def __buffer__(self, flags: int) -> memoryview:
+        return self.view
+
+
+def dense_xor(xor) -> bool:
+    """More than half of the XOR's bytes are nonzero: zero-run RLE cannot
+    shrink it, so its chunk is cheaper stored raw."""
+    a = np.frombuffer(xor, np.uint8)
+    return int(np.count_nonzero(a)) * 2 > a.size
+
+
 def is_delta_ref(ref: str) -> bool:
     return ref.startswith(DELTA_PREFIX)
 
@@ -252,8 +288,9 @@ class ChunkStore:
         scope = self.tel.scope("chunkstore")
         self.metrics = scope.counters(
             "put_bytes", "dedup_bytes", "get_bytes", "put_chunks",
-            "dedup_chunks", "delta_chunks", "rebased", "ingest_bytes",
-            "ingest_dedup_bytes", "ingest_records", "egress_bytes")
+            "dedup_chunks", "delta_chunks", "rebased", "dense_chunks",
+            "ingest_bytes", "ingest_dedup_bytes", "ingest_records",
+            "egress_bytes")
         self.stats = scope.view()
         # per-client uplink accounting (client id -> counters); the server
         # credits volunteers by the deduped bytes they actually moved
@@ -277,7 +314,12 @@ class ChunkStore:
         return ref in self._mem or self._path(ref).exists()
 
     def put(self, data: bytes) -> str:
-        h = sha256(data)
+        """Store one raw chunk; -> its sha256 ref.  The object kept is a
+        copy of ``data``, or the read-only copy a ``Digested`` holds."""
+        owned = isinstance(data, Digested)
+        h = data.ref if owned else sha256(data)
+        if owned:
+            data = data.view
         with self._lock:
             if self.has(h):
                 self.metrics.dedup_bytes.inc(len(data))
@@ -288,7 +330,7 @@ class ChunkStore:
             if self.tel.tracing:
                 self.tel.event("put", ref=h[:16], bytes=len(data))
             if self.root is None:
-                self._mem[h] = bytes(data)
+                self._mem[h] = data if owned else bytes(data)
             else:
                 self._atomic_write(self._path(h), data)
         return h
@@ -354,13 +396,24 @@ class ChunkStore:
         Returns the new ref.  Transparently rebases to a raw object when
         the chain would exceed ``max_chain`` or the delta record would be
         no smaller than the chunk itself (``full_bytes``, when given,
-        avoids a resolve to materialize the rebase)."""
+        avoids a resolve to materialize the rebase).  Both may be any
+        contiguous byte buffer (``bytes``, or a uint8 array or view).
+
+        A dense XOR (more than half its bytes nonzero) with ``full_bytes``
+        no longer than it is stored raw at once (``dense_chunks``): zero-run
+        RLE would bail to a literal 5 B longer than the XOR, so the packed
+        record would outgrow the chunk and land on the same ``put``."""
         depth = self.ref_depth(parent_ref) + 1
         if depth > self.max_chain:
             full = full_bytes if full_bytes is not None else _xor_bytes(
                 self.resolve(parent_ref), xor_bytes)
             self.metrics.rebased.inc()
             return self.put(full)
+        if (full_bytes is not None and len(full_bytes) <= len(xor_bytes)
+                and dense_xor(xor_bytes)):
+            self.metrics.dense_chunks.inc()
+            return self.put(full_bytes)
+        xor_bytes = bytes(xor_bytes)
         payload = rle_zero_encode(xor_bytes)
         compressed = True
         if len(payload) >= len(xor_bytes):

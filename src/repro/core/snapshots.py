@@ -19,7 +19,8 @@ anything crosses the device→host boundary:
 * **Async writer** (``async_mode=True``) — the calling thread runs ONLY
   the device probe + changed-tile transfer (``probe_leaves``); chunk
   compaction, hashing, RLE, ``put_delta`` and deferred ``max_chain``
-  rebase run on a background ``SnapshotWriter`` behind a bounded queue,
+  rebase run on a background ``SnapshotWriter`` behind a bounded queue
+  (dense chunks are copied and hashed on a small pool it feeds),
   so the trainer's stall is the probe and nothing else (the caller's
   ``snapshot`` span vs the writer's ``writer.write``).  Plans are
   self-contained (they carry the changed tiles + bitmap, or the full
@@ -49,9 +50,10 @@ from __future__ import annotations
 
 import contextlib
 import json
+import os
 import time
 from collections import deque
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional
@@ -60,12 +62,16 @@ import jax
 import numpy as np
 
 from repro.core import telemetry as tlm
-from repro.core.chunkstore import ChunkStore, sha256
+from repro.core.chunkstore import ChunkStore, Digested, dense_xor, sha256
 from repro.core.writer import SnapshotWriter
 from repro.kernels.delta_encode.ops import (DeviceMirror, chunk_records,
                                             probe_leaves)
 
 MANIFEST_VERSION = 2
+
+# threads that copy and hash a write's dense chunks while the store takes
+# them in order; a quarter of the host's cores, the rest left to training
+HASH_WORKERS = max(1, (os.cpu_count() or 1) // 4)
 
 
 def _flatten(tree) -> list[tuple[str, Any]]:
@@ -196,6 +202,7 @@ class SnapshotManager:
         self._mirror: Dict[str, np.ndarray] = {}
         self._device_mirror = DeviceMirror()       # probe-side tiles (no H→D)
         self._prev_refs: Dict[str, List[str]] = {}
+        self._hashers: Optional[ThreadPoolExecutor] = None
 
     @property
     def is_async(self) -> bool:
@@ -254,12 +261,14 @@ class SnapshotManager:
         return out
 
     def close(self) -> None:
-        """Drain the writer and stop its thread."""
+        """Drain the writer and stop its threads."""
         try:
             self.wait()
         finally:
             if self._writer is not None:
                 self._writer.close()
+            if self._hashers is not None:
+                self._hashers.shutdown()
 
     def _reap(self) -> None:
         """Non-blocking: collect already-finished async writes (keeps the
@@ -338,9 +347,11 @@ class SnapshotManager:
 
     def _write_inner(self, plan: List[_TensorPlan], step: int,
                      aux: dict) -> SnapshotInfo:
-        """Persist one plan: per tensor, fold the probe's tiles into the
-        host image (``writer.records`` span), then store its changed
-        chunks (``writer.put``); then register the manifest."""
+        """Persist one plan: per tensor, advance the host image by the
+        probe's tiles and take the changed chunks' XOR views
+        (``writer.records`` span), then store those chunks (``writer.put``;
+        ``put_delta`` stores a dense one raw); then register the
+        manifest."""
         before_put = self.store.stats["put_bytes"]
         before_dedup = self.store.stats["dedup_bytes"]
         cb = self.store.chunk_bytes
@@ -357,20 +368,24 @@ class SnapshotManager:
                     with self.tel.span("writer.put"):
                         refs = self.store.put_buffer(memoryview(flat))
                     changed += len(refs)
-                    self._mirror[p.key] = flat
+                    # the writer advances this image in place from now on
+                    self._mirror[p.key] = flat if flat.flags.writeable \
+                        else flat.copy()
                 else:
-                    # fold the probe's tiles into the writer's host image
-                    # and derive per-chunk XOR records — off the hot path
+                    # advance the writer's host image by the probe's tiles
+                    # and take per-chunk XOR views — off the hot path.  A
+                    # failure past here leaves the image ahead of
+                    # _prev_refs; _poison drops both.
                     prev_refs = self._prev_refs[p.key]
-                    records: Dict[int, bytes] = {}
-                    new_flat = None
+                    records: Dict[int, np.ndarray] = {}
+                    new_flat = self._mirror[p.key]
                     if p.bitmap is not None and p.bitmap.any():
                         with self.tel.span("writer.records"):
                             records, new_flat = chunk_records(
-                                self._mirror[p.key], p.tiles, p.bitmap,
-                                p.nbytes, cb)
+                                new_flat, p.tiles, p.bitmap, p.nbytes, cb)
                     refs = []
                     with self.tel.span("writer.put"):
+                        fulls = self._full_chunks(records, new_flat, cb)
                         for ci, pref in enumerate(prev_refs):
                             xor = records.get(ci)
                             if xor is None:
@@ -379,14 +394,10 @@ class SnapshotManager:
                                 reused_bytes += max(
                                     0, min((ci + 1) * cb, p.nbytes) - ci * cb)
                             else:
-                                cs = ci * cb
-                                ce = min(cs + cb, p.nbytes)
                                 refs.append(self.store.put_delta(
-                                    pref, xor,
-                                    full_bytes=new_flat[cs:ce].tobytes()))
+                                    pref, memoryview(xor),
+                                    full_bytes=next(fulls)))
                                 changed += 1
-                    if new_flat is not None:
-                        self._mirror[p.key] = new_flat
                 tensors[p.key] = TensorEntry(p.shape, p.dtype, refs)
                 self._prev_refs[p.key] = refs
             # chain reuse counts as dedup, as the v1 hash-everything path did
@@ -409,6 +420,20 @@ class SnapshotManager:
             dedup_bytes=self.store.stats["dedup_bytes"] - before_dedup,
             total_bytes=total,
             changed_chunks=changed, reused_chunks=reused)
+
+    def _full_chunks(self, records: Dict[int, np.ndarray],
+                     new_flat: np.ndarray, cb: int):
+        """The changed chunks' new bytes, in chunk order, as ``put_delta``
+        takes them: a dense chunk's (``dense_xor``, the store's own rule)
+        copied and hashed on the hash pool, any other as a view."""
+        if self._hashers is None:
+            self._hashers = ThreadPoolExecutor(
+                HASH_WORKERS, thread_name_prefix="snapshot-hash")
+
+        def full(ci: int):
+            chunk = memoryview(new_flat[ci * cb:(ci + 1) * cb])
+            return Digested(chunk) if dense_xor(records[ci]) else chunk
+        return self._hashers.map(full, sorted(records))
 
     def _gc_guard(self):
         lock = getattr(self.store, "gc_lock", None)

@@ -222,11 +222,12 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
 
     ``emit="records"`` is the *upload* mode: instead of raw tiles it
     returns ``(records, new_flat, nbytes)`` where ``records`` maps
-    store-chunk index -> XOR bytes for exactly the chunks whose bytes
-    changed — the per-chunk payloads ``ChunkStore.put_delta``/``ingest``
-    expect — and ``new_flat`` is the updated uint8 host image (the
-    caller's next mirror).  Requires ``chunk_bytes``.  Both the snapshot
-    differencing path and the volunteer uplink encoder ride this mode.
+    store-chunk index -> XOR (a uint8 array) for exactly the chunks whose
+    bytes changed — the per-chunk payloads ``ChunkStore.put_delta``
+    expects — and ``new_flat`` is the updated uint8 host image, a copy of
+    ``old`` advanced in place (the caller's next mirror).  Requires
+    ``chunk_bytes``.  Both the snapshot differencing path and the
+    volunteer uplink encoder ride this mode.
     """
     host_old = old
     mode = _check_dtypes(old, new, _resolve_mode(mode))
@@ -279,45 +280,76 @@ def changed_blocks(old, new, *, mode: str = "auto", emit: str = "tiles",
         raise ValueError(f"unknown emit mode {emit!r}")
     if chunk_bytes <= 0:
         raise ValueError("emit='records' requires chunk_bytes")
-    records, new_flat = chunk_records(np.asarray(host_old), tiles, bitmap,
-                                      nbytes, chunk_bytes)
+    records, new_flat = chunk_records(np.array(host_old, order="C"), tiles,
+                                      bitmap, nbytes, chunk_bytes)
     return records, new_flat, nbytes
 
 
 def chunk_records(prev: np.ndarray, tiles: np.ndarray, bitmap: np.ndarray,
                   nbytes: int, chunk_bytes: int):
-    """Compact changed tiles into store-ready per-chunk XOR records.
+    """Advance a host image by the probe's XOR tiles, in place, and split
+    the change into store-ready per-chunk XOR records.
 
-    -> (records: {chunk index -> XOR bytes}, new_flat uint8 image).
-    Tiles (32 KiB probe granules) rarely align with store chunks; a chunk
-    is recorded only when its bytes actually differ, so a tile flip that
-    straddles two chunks but only dirties one emits one record.
+    -> (records: {chunk index -> XOR uint8 array}, new_flat uint8 image).
+    ``prev`` must be a writable, C-contiguous array the caller owns:
+    ``new_flat`` is its own buffer, advanced by one contiguous XOR per run
+    of consecutive changed tiles.  A chunk whose overlapping tiles all
+    changed takes its XOR as a view of ``tiles`` (their compacted slots
+    are consecutive); any other chunk's XOR is assembled, zero-filled.
+    Tiles (32 KiB probe granules) need not align with store chunks; a
+    chunk is recorded only when its bytes differ.  A chunk that holds a
+    whole changed tile does (a changed tile's XOR is nonzero within the
+    image); only the others are scanned, so a tile flip that straddles two
+    chunks but dirties one emits one record.
     """
-    old_flat = np.ascontiguousarray(prev).reshape(-1).view(np.uint8)
+    if not (prev.flags.writeable and prev.flags.c_contiguous):
+        raise ValueError("chunk_records advances prev in place: it must "
+                         "be writable and C-contiguous")
+    flat = prev.reshape(-1).view(np.uint8)
     if not bitmap.any():
-        return {}, old_flat    # unchanged leaf: no records, no host copy
-    new_flat = apply_tiles(old_flat.copy(), tiles, bitmap)
+        return {}, flat        # unchanged leaf: no records
+    ti = np.flatnonzero(bitmap)
+    tb = np.ascontiguousarray(tiles[:ti.size]).reshape(-1).view(np.uint8)
+    # slot of each changed tile in ``tb``: rank[t] changed tiles precede t
+    rank = np.concatenate(([0], np.cumsum(bitmap != 0)))
+    brk = np.flatnonzero(np.diff(ti) != 1) + 1
+    for r0, r1 in zip(np.concatenate(([0], brk)),
+                      np.concatenate((brk, [ti.size]))):
+        s = int(ti[r0]) * TILE_BYTES
+        e = min(int(ti[r1 - 1]) * TILE_BYTES + TILE_BYTES, nbytes)
+        if e > s:
+            flat[s:e] ^= tb[int(r0) * TILE_BYTES:int(r0) * TILE_BYTES + e - s]
     # touched chunk set, vectorized: each changed tile covers byte range
     # [s, e) which spans chunks [s // cb, (e-1) // cb]
-    ti = np.flatnonzero(bitmap)
     s = ti * TILE_BYTES
     e = np.minimum(s + TILE_BYTES, nbytes)
     valid = e > s
     s, e = s[valid], e[valid]
-    records: dict[int, bytes] = {}
+    records: dict[int, np.ndarray] = {}
     if s.size == 0:
-        return records, new_flat
+        return records, flat
     c0, c1 = s // chunk_bytes, (e - 1) // chunk_bytes
     width = int((c1 - c0).max()) + 1         # chunks per tile, usually <= 2
     cand = c0[:, None] + np.arange(width)[None, :]
     chunks = np.unique(cand[cand <= c1[:, None]])
-    for ci in chunks:
-        cs, ce = int(ci) * chunk_bytes, min((int(ci) + 1) * chunk_bytes,
-                                            nbytes)
-        xor = old_flat[cs:ce] ^ new_flat[cs:ce]
-        if xor.any():
-            records[int(ci)] = xor.tobytes()
-    return records, new_flat
+    for ci in chunks.tolist():
+        cs, ce = ci * chunk_bytes, min((ci + 1) * chunk_bytes, nbytes)
+        t0, t1 = cs // TILE_BYTES, (ce - 1) // TILE_BYTES + 1
+        if rank[t1] - rank[t0] == t1 - t0:
+            off = int(rank[t0]) * TILE_BYTES + cs - t0 * TILE_BYTES
+            xor = tb[off:off + ce - cs]
+        else:
+            xor = np.zeros(ce - cs, np.uint8)
+            for t in ti[rank[t0]:rank[t1]].tolist():
+                a, b = max(t * TILE_BYTES, cs), min((t + 1) * TILE_BYTES, ce)
+                off = int(rank[t]) * TILE_BYTES - t * TILE_BYTES
+                xor[a - cs:b - cs] = tb[off + a:off + b]
+        # tiles [f0, f1) lie wholly inside the chunk's bytes
+        f0 = -(-cs // TILE_BYTES)
+        f1 = t1 if ce == nbytes else ce // TILE_BYTES
+        if (f1 > f0 and rank[f1] > rank[f0]) or xor.any():
+            records[ci] = xor
+    return records, flat
 
 
 def _leaf_ntiles(nbytes: int) -> int:
@@ -601,31 +633,3 @@ def _ref_tiles(x) -> np.ndarray:
     if pad:
         b = np.concatenate([b, np.zeros(pad, np.uint8)])
     return b.view(np.int32).reshape(-1, SUB, LANE)
-
-
-def apply_tiles(flat_u8: np.ndarray, tiles: np.ndarray,
-                bitmap: np.ndarray) -> np.ndarray:
-    """XOR compacted changed tiles into a flat uint8 buffer, in place.
-
-    ``flat_u8`` is the previous state's byte image; tile ``i`` covers bytes
-    ``[i*TILE_BYTES, (i+1)*TILE_BYTES)`` of the (padded) stream — the tail
-    tile is clipped to the buffer length.  Returns ``flat_u8``.
-    """
-    nbytes = flat_u8.size
-    idx = np.flatnonzero(bitmap)
-    if idx.size == 0:
-        return flat_u8
-    tb = np.ascontiguousarray(tiles[:idx.size]).reshape(idx.size, -1) \
-        .view(np.uint8)                       # (k, TILE_BYTES)
-    nfull = nbytes // TILE_BYTES
-    body = idx < nfull
-    if body.any():
-        # one reshaped scatter-XOR for every whole tile
-        view = flat_u8[:nfull * TILE_BYTES].reshape(nfull, TILE_BYTES)
-        view[idx[body]] ^= tb[body]
-    for j in np.flatnonzero(~body):           # at most the one tail tile
-        s = int(idx[j]) * TILE_BYTES
-        e = min(s + TILE_BYTES, nbytes)
-        if e > s:
-            flat_u8[s:e] ^= tb[j, :e - s]
-    return flat_u8

@@ -279,29 +279,56 @@ def test_server_reattach_moves_only_deltas():
 # ---------------------------------------------------------------------------
 # failure hygiene: a failed store write must not poison later snapshots
 # ---------------------------------------------------------------------------
+def _changed(x, at: int, value: float, dense: bool):
+    """``x`` with one element set (sparse), or with every element moved
+    as well (dense: most XOR bytes of every chunk nonzero)."""
+    y = x * np.float32(1.0001) + np.float32(1e-4) if dense else x.copy()
+    y[at] = value
+    return y
+
+
 def test_failed_write_does_not_corrupt_next_snapshot():
+    _failed_write_then_recover(dense=False)
+
+
+def test_failed_write_does_not_corrupt_next_snapshot_dense():
+    _failed_write_then_recover(dense=True)
+
+
+def _failed_write_then_recover(dense: bool):
     store = ChunkStore(chunk_bytes=1 << 12)
     mgr = SnapshotManager(store)
     x = np.random.default_rng(6).standard_normal(20_000).astype(np.float32)
     mgr.snapshot({"x": x}, step=0)
-    y = x.copy()
-    y[3] = 9.0
+    y = _changed(x, 3, 9.0, dense)
     real_put_delta = store.put_delta
     store.put_delta = lambda *a, **k: (_ for _ in ()).throw(IOError("disk"))
     with pytest.raises(IOError):
         mgr.snapshot({"x": y}, step=1)   # planning advanced the mirror...
     store.put_delta = real_put_delta
-    z = y.copy()
-    z[4] = 10.0
+    z = _changed(y, 4, 10.0, dense)
     mgr.snapshot({"x": z}, step=2)       # ...but recovery re-bases cleanly
     got, _ = mgr.restore(target_tree={"x": np.zeros_like(x)})
     assert np.array_equal(_bits(got["x"]), _bits(z))
+    w = _changed(z, 5, 11.0, dense)      # and the chain diffs on from it
+    mgr.snapshot({"x": w}, step=3)
+    got, _ = mgr.restore(target_tree={"x": np.zeros_like(x)})
+    assert np.array_equal(_bits(got["x"]), _bits(w))
+    assert (store.stats["dense_chunks"] > 0) == dense
 
 
 def test_failed_planning_does_not_corrupt_next_snapshot(monkeypatch):
     """A plan-phase failure (e.g. device OOM mid-diff) advances some
     tensors' mirrors but not their refs; the next snapshot must re-base
     rather than record stale parent refs."""
+    _failed_planning_then_recover(monkeypatch, dense=False)
+
+
+def test_failed_planning_does_not_corrupt_next_snapshot_dense(monkeypatch):
+    _failed_planning_then_recover(monkeypatch, dense=True)
+
+
+def _failed_planning_then_recover(monkeypatch, dense: bool):
     import repro.core.snapshots as snapmod
 
     store = ChunkStore(chunk_bytes=1 << 12)
@@ -321,13 +348,11 @@ def test_failed_planning_does_not_corrupt_next_snapshot(monkeypatch):
         return real(*a_, **kw)
 
     monkeypatch.setattr(snapmod, "chunk_records", boom)
-    a2, b2 = a.copy(), b.copy()
-    a2[0], b2[0] = 1.5, 2.5
+    a2, b2 = _changed(a, 0, 1.5, dense), _changed(b, 0, 2.5, dense)
     with pytest.raises(RuntimeError):
         mgr.snapshot({"a": a2, "b": b2}, step=1)
     monkeypatch.setattr(snapmod, "chunk_records", real)
-    a3, b3 = a2.copy(), b2.copy()
-    a3[1], b3[1] = 3.5, 4.5
+    a3, b3 = _changed(a2, 1, 3.5, dense), _changed(b2, 1, 4.5, dense)
     mgr.snapshot({"a": a3, "b": b3}, step=2)
     got, _ = mgr.restore(target_tree={"a": np.zeros_like(a),
                                       "b": np.zeros_like(b)})

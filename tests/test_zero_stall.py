@@ -250,6 +250,24 @@ def test_async_manifests_byte_identical_to_inline():
 
 
 def test_async_write_failure_is_invisible_and_rebases():
+    _async_write_failure(dense=True)
+
+
+def test_async_write_failure_is_invisible_and_rebases_sparse():
+    _async_write_failure(dense=False)
+
+
+def _bumped(state: dict, dense: bool) -> dict:
+    """Every element of both leaves moved (dense), or one of each."""
+    if dense:
+        return {k: v + 1.0 for k, v in state.items()}
+    out = {k: v.copy() for k, v in state.items()}
+    for v in out.values():
+        v[5] += 1.0
+    return out
+
+
+def _async_write_failure(dense: bool):
     rng = np.random.default_rng(22)
     store = ChunkStore()
     mgr = SnapshotManager(store, keep_last=5, async_mode=True,
@@ -268,7 +286,7 @@ def test_async_write_failure_is_invisible_and_rebases():
         return real(*a, **kw)
 
     store.put_delta = bomb
-    s1 = {"w": s0["w"] + 1.0, "m": s0["m"] + 1.0}   # >= 2 delta chunks
+    s1 = _bumped(s0, dense)                          # >= 2 delta chunks
     mgr.snapshot(s1, step=1, block=False)
     with pytest.raises(OSError):
         mgr.wait()
@@ -283,6 +301,12 @@ def test_async_write_failure_is_invisible_and_rebases():
     restored, _ = mgr.restore()
     np.testing.assert_array_equal(restored["['w']"], s2["w"])
     np.testing.assert_array_equal(restored["['m']"], s2["m"])
+    s3 = _bumped(s2, dense)              # the chain diffs on from the base
+    mgr.snapshot(s3, step=3, block=True)
+    restored, _ = mgr.restore()
+    np.testing.assert_array_equal(restored["['w']"], s3["w"])
+    np.testing.assert_array_equal(restored["['m']"], s3["m"])
+    assert (store.stats["dense_chunks"] > 0) == dense
     mgr.close()
 
 
@@ -292,6 +316,17 @@ def test_writer_gc_pump_interleaving_never_tears_snapshot(seed):
     outbox concurrently; a scrubber resolves the LATEST committed manifest
     the whole time.  Every committed snapshot must stay fully resolvable
     (never torn), and the final restore must be bit-exact."""
+    _interleave(seed, dense=False)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_writer_gc_pump_interleaving_never_tears_snapshot_dense(seed):
+    """As above, with rounds that move every element, so the writer
+    stores each changed chunk raw."""
+    _interleave(seed, dense=True)
+
+
+def _interleave(seed: int, dense: bool):
     rng = np.random.default_rng(seed)
     rs = ReplicaSet(ChunkStore(), [ChunkStore()])
     mgr = SnapshotManager(rs, keep_last=3, async_mode=True,
@@ -332,7 +367,8 @@ def test_writer_gc_pump_interleaving_never_tears_snapshot(seed):
     try:
         for step in range(12):
             idx = rng.integers(0, state["w"].size, 60)
-            w = state["w"].copy()
+            w = state["w"] * np.float32(1.0001) if dense \
+                else state["w"].copy()
             w[idx] += 1.0
             state = {"w": w, "m": state["m"]}
             mgr.snapshot(state, step=step, block=False)
@@ -344,5 +380,6 @@ def test_writer_gc_pump_interleaving_never_tears_snapshot(seed):
     assert not errors, errors
     restored, _ = mgr.restore()
     np.testing.assert_array_equal(restored["['w']"], state["w"])
+    assert (rs.primary.stats["dense_chunks"] > 0) == dense
     rs.flush()
     mgr.close()
