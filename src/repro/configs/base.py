@@ -18,11 +18,43 @@ def _round_up(x: int, m: int) -> int:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 0            # routed experts
+    n_experts: int = 0            # routed experts (the router's width)
     n_shared_experts: int = 0     # always-on shared experts (DeepSeekMoE)
     top_k: int = 0
     d_ff_expert: int = 0          # per-expert hidden size
     router_aux_coef: float = 0.01
+    # routed experts whose weights this device holds (expert parallelism:
+    # a chip's share); 0 -> all of them
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class Rope:
+    """Rotary positions of one layer kind: ``theta``, and YaRN's frequency
+    ramp where ``factor`` > 1 (transformers' ``_compute_yarn_parameters``:
+    interpolate the slow dimensions by ``factor``, keep the fast ones, ramp
+    between the correction dimensions of ``beta_fast`` and ``beta_slow``
+    rotations over ``original_max_positions``; cos and sin scaled by
+    ``attention_factor``)."""
+    theta: float = 10_000.0
+    factor: float = 1.0
+    original_max_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """What may differ between the layers of one stack: the attention
+    window (0 = full causal) and the rotary positions.  Parameter shapes
+    never differ."""
+    window: int = 0
+    rope: Rope = Rope()
 
 
 @dataclass(frozen=True)
@@ -55,6 +87,11 @@ class ArchConfig:
     frontend: Optional[str] = None        # None | "vq_image" | "audio_frames"
     # sliding-window attention (beyond-paper extra enabling long ctx on dense)
     window: int = 0                        # 0 -> full attention
+    # per-layer kinds of the published stack (its ``layer_types``); empty:
+    # every layer is ``LayerKind(window, Rope(rope_theta))``
+    layer_types: Tuple[LayerKind, ...] = ()
+    # a chip's share of a published config (``expert_share``): its name
+    share_of: str = ""
     source: str = ""                       # provenance note
 
     # ------------------------------------------------------------------
@@ -84,12 +121,23 @@ class ArchConfig:
     def dt_rank(self) -> int:
         return self.ssm.dt_rank or max(1, self.d_model // 16)
 
+    def layer_kinds(self) -> Tuple[LayerKind, ...]:
+        """The kind of each of the ``n_layers`` layers, in order."""
+        if not self.layer_types:
+            return (LayerKind(self.window, Rope(self.rope_theta)),) \
+                * self.n_layers
+        return tuple(self.layer_types[i % len(self.layer_types)]
+                     for i in range(self.n_layers))
+
     def padded_vocab(self, multiple: int = 128) -> int:
         """Vocab padded for MXU alignment + mesh divisibility (DESIGN.md §4)."""
         return _round_up(self.vocab_size, multiple)
 
     def param_count(self) -> int:
-        """Analytic parameter count (used for roofline 6*N*D)."""
+        """Analytic parameter count (used for roofline 6*N*D): the experts
+        held, the router at its full width, the vocabulary as held.  Layer
+        kinds differ in window and rotary positions only, which hold no
+        parameters, so every layer counts alike."""
         d, hd = self.d_model, self.resolved_head_dim
         v = self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
@@ -107,7 +155,7 @@ class ArchConfig:
                 + di * st + di + di * d
         if self.is_moe:
             fe = self.moe.d_ff_expert
-            routed = self.moe.n_experts * 3 * d * fe
+            routed = self.moe.held * 3 * d * fe
             shared = self.moe.n_shared_experts * 3 * d * fe
             per_layer += routed + shared + d * self.moe.n_experts
         elif self.d_ff:
@@ -120,12 +168,14 @@ class ArchConfig:
         return emb + n_layers * per_layer
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top_k + shared experts only)."""
+        """Params touched per token (MoE: shared experts, and of the routed
+        experts held the ``top_k * held / n_experts`` a token reaches here
+        on average; ``top_k`` when all are held)."""
         if not self.is_moe:
             return self.param_count()
-        d = self.d_model
-        fe = self.moe.d_ff_expert
-        inactive = (self.moe.n_experts - self.moe.top_k) * 3 * d * fe
+        m = self.moe
+        expert = 3 * self.d_model * m.d_ff_expert
+        inactive = m.held * expert - m.top_k * m.held * expert // m.n_experts
         return self.param_count() - self.n_layers * inactive
 
 
@@ -189,6 +239,32 @@ def _ensure_loaded() -> None:
     from repro.configs import all_configs  # noqa: F401
 
 
+def expert_share(cfg: ArchConfig, chips: int) -> ArchConfig:
+    """One chip's share of ``cfg`` when each layer is divided over ``chips``
+    (expert parallelism): ``n_experts // chips`` experts held, the router
+    at its full width, and ``vocab_size // chips`` rows of the vocabulary.
+    Depth is cut at launch (``--layers``): the layers left out stand for
+    further pipeline stages."""
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}-ep{chips}", vocab_size=cfg.vocab_size // chips,
+        moe=dataclasses.replace(cfg.moe,
+                                experts_held=cfg.moe.n_experts // chips),
+        share_of=cfg.name)
+
+
+def share_cuts(cfg: ArchConfig) -> dict:
+    """-> {key: [here, published]} for what a share cuts from its source."""
+    if not cfg.share_of:
+        return {}
+    whole = get_arch(cfg.share_of)
+    cuts = {}
+    if cfg.moe.held != whole.moe.held:
+        cuts["experts_held"] = [cfg.moe.held, whole.moe.held]
+    if cfg.vocab_size != whole.vocab_size:
+        cuts["vocab_size"] = [cfg.vocab_size, whole.vocab_size]
+    return cuts
+
+
 def reduced(cfg: ArchConfig, *, n_layers: int = 2, d_model: int = 64,
             n_heads: int = 4, n_kv_heads: int = 0, d_ff: int = 128,
             vocab_size: int = 256) -> ArchConfig:
@@ -201,7 +277,8 @@ def reduced(cfg: ArchConfig, *, n_layers: int = 2, d_model: int = 64,
     )
     if cfg.is_moe:
         kw["moe"] = MoEConfig(n_experts=4, n_shared_experts=cfg.moe.n_shared_experts and 1,
-                              top_k=2, d_ff_expert=32)
+                              top_k=2, d_ff_expert=32,
+                              experts_held=2 if cfg.moe.experts_held else 0)
         kw["d_ff"] = 0
     if cfg.family in ("ssm", "hybrid"):
         kw["ssm"] = SSMConfig(d_state=4, d_conv=4, expand=2, dt_rank=8)

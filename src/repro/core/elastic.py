@@ -99,7 +99,11 @@ class VolunteerTrainer:
                  uplink_mode: str = "auto",
                  replicas=None, edge=None,
                  telemetry: Optional[tlm.Telemetry] = None):
-        """grad_fn(params, batch)->(loss, grads); apply_fn(state, grads)->state.
+        """grad_fn(params, batch)->(loss, grads) or ((loss, routing), grads)
+        (``api.make_grad_step``); apply_fn(state, grads)->state.  A grad
+        step's routing counts (``moe.moe_apply``: per layer, the pairs
+        computed here and elsewhere, the largest held expert's pairs) go
+        to the ``moe`` scope of the hub, and one reading per unit.
 
         ``scheduler`` may be a single ``VolunteerScheduler`` or a
         ``ShardedScheduler`` plane (``core/shardplane.py``) — the trainer
@@ -172,6 +176,7 @@ class VolunteerTrainer:
                                        "validated_results",
                                        "validated_bytes")
         self.tstats = scope.view()
+        self._moe = None                 # the ``moe`` scope, on first use
         # unit -> {worker: (moved, dedup)} awaiting quorum validation
         self._pending_credit: Dict[int, Dict[str, tuple]] = {}
         self.last_restore_plan: Optional[dict] = None
@@ -208,16 +213,41 @@ class VolunteerTrainer:
         batch = self.stream.batch(unit.payload["batch_index"])
         sub = {k: v for k, v in batch.items()}
         with self.tel.span("grad_step", unit=unit.unit_id):
-            loss, grads = self.grad_fn(self.state.params, sub)
+            out, grads = self.grad_fn(self.state.params, sub)
+        loss, routing = out if isinstance(out, tuple) else (out, None)
         if self.uplink:
             self._execute_unit_uplink(worker, unit, float(loss), grads)
-            return
-        h = self._validate(unit, grads)
-        if worker.rng.random() < worker.corrupt_prob:
-            h = "corrupt-" + h[:16]        # wrong result; quorum rejects
         else:
-            self._grad_cache[h] = (float(loss), grads)
-        self.sched.report(worker.worker_id, unit.unit_id, h)
+            h = self._validate(unit, grads)
+            if worker.rng.random() < worker.corrupt_prob:
+                h = "corrupt-" + h[:16]        # wrong result; quorum rejects
+            else:
+                self._grad_cache[h] = (float(loss), grads)
+            self.sched.report(worker.worker_id, unit.unit_id, h)
+        if routing:
+            # read once validation has waited for the step on the host, so
+            # the wait stays inside the spans that hold it
+            self._count_routing(unit, routing)
+
+    def _count_routing(self, unit, routing: dict) -> None:
+        """One unit's routing counts into the ``moe`` scope and readings:
+        pairs computed here and routed elsewhere (summed over layers), and
+        the largest held expert's pairs in any layer."""
+        if self._moe is None:
+            sc = self.tel.scope("moe")
+            self._moe = (sc.counter("pairs_here"),
+                         sc.counter("pairs_elsewhere"),
+                         sc.gauge("max_expert_pairs"))
+        here, away, top = self._moe
+        values = (int(np.sum(routing["moe_pairs_here"])),
+                  int(np.sum(routing["moe_pairs_elsewhere"])),
+                  int(np.max(routing["moe_max_expert_pairs"])))
+        here.inc(values[0])
+        away.inc(values[1])
+        top.set(max(top.value, values[2]))
+        for metric, v in zip(self._moe, values):
+            self.tel.reading("moe." + metric.name, v,
+                             step=unit.payload.get("step"), unit=unit.unit_id)
 
     def _validate(self, unit, grads) -> str:
         """Hash one result for quorum validation, counting it."""

@@ -35,7 +35,11 @@ by every layer built since PR 1:
   belong to, and may name a ``cause`` span on another thread (a
   background write names the snapshot that queued it).  They are always
   recorded, into a bounded ring of their own (``Telemetry.spans``); they
-  never enter the event ring, whose stream stays on the hub's clock.
+  never enter the event ring, whose stream stays on the hub's clock;
+* **readings** — one value of a counter taken per work unit
+  (:meth:`Telemetry.reading`), kept with its round (``step``) and unit in
+  a bounded ring (``Telemetry.readings``) beside the counter's running
+  total, so a reader can pick the rounds it measures.
 
 The hub is process-wide by default (module-level instance, so components
 constructed without an explicit ``telemetry=`` all share it) but fully
@@ -100,10 +104,11 @@ from collections import deque
 from collections.abc import Mapping
 from dataclasses import dataclass, field
 from types import SimpleNamespace
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricScope", "Span", "StatsView",
+    "Counter", "Gauge", "Histogram", "MetricScope", "Reading", "Span",
+    "StatsView",
     "Telemetry", "TraceReport", "get_default", "set_default", "resolve",
     "span", "trace_reduce", "TIME_BUCKETS_S", "SIZE_BUCKETS",
 ]
@@ -260,6 +265,14 @@ class MetricScope:
         return StatsView(self._scalars)
 
 
+class Reading(NamedTuple):
+    """One per-unit value of a counter (``<scope>.<key>``)."""
+    name: str
+    value: float
+    step: Optional[int]
+    unit: Optional[int]
+
+
 # span ids are unique per process, so a parent or cause may sit on another
 # hub or thread; the open spans of each thread form its own stack
 _SPAN_IDS = itertools.count(1)
@@ -360,6 +373,7 @@ class Telemetry:
         self.capacity = int(capacity)
         self.events: deque = deque(maxlen=self.capacity)
         self.spans: deque = deque(maxlen=self.capacity)
+        self.readings: deque = deque(maxlen=self.capacity)
         self._seq = 0
         self._scopes: List[MetricScope] = []
         self._scope_counts: Dict[str, int] = {}
@@ -404,6 +418,12 @@ class Telemetry:
         """A timed span recorded into this hub's span ring; use it as a
         context manager, and read the duration from its ``ms`` after."""
         return Span(self, name, step, unit, cause)
+
+    # ---------------- readings ----------------
+    def reading(self, name: str, value, *, step=None, unit=None) -> None:
+        """Keep one per-unit value of the counter ``name`` with its round
+        and unit; the counter's total is its scope's to keep."""
+        self.readings.append(Reading(name, value, step, unit))
 
     # ---------------- exporters ----------------
     def event_lines(self) -> List[str]:

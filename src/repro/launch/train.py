@@ -12,7 +12,8 @@ snapshots, and survives worker failures / restarts.
 
 ``--preset full`` keeps the assigned architecture at every published
 width; ``--layers N`` cuts its depth only (the summary's ``reduced``
-records the cut), which is how one chip holds it:
+records the cut, with a share arch's held experts and vocabulary, e.g.
+``mellum2-12b-a2.5b-ep8``), which is how one chip holds it:
 
     python -m repro.launch.train --arch granite-3-2b --preset full \
         --layers 1 --seq 1024 --batch 2 --snapshot-every 1 --async-writer
@@ -31,7 +32,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro.configs.base import get_arch, reduced
+from repro.configs.base import get_arch, reduced, share_cuts
 from repro.core import telemetry as tlm
 from repro.core.chunkstore import ChunkStore
 from repro.core.elastic import SimWorker, VolunteerTrainer
@@ -46,10 +47,12 @@ from repro.optim import adamw
 
 
 def build_arch(name: str, preset: str, layers: int = 0):
-    """-> (config, ``reduced`` record of the cuts made to the full preset).
+    """-> (config, ``reduced`` record of the cuts made to the full preset:
+    {key: [here, published]}).
 
     ``layers`` cuts the depth of ``full`` only; every width stays as
-    published."""
+    published.  A share arch (``configs.base.expert_share``) records the
+    experts and vocabulary rows it holds of its source as well."""
     cfg = get_arch(name)
     if preset != "full":
         if layers:
@@ -61,12 +64,13 @@ def build_arch(name: str, preset: str, layers: int = 0):
             return reduced(cfg, n_layers=6, d_model=512, n_heads=8,
                            n_kv_heads=4, d_ff=2048, vocab_size=32768), {}
         raise ValueError(preset)
+    cuts = share_cuts(cfg)
     if not layers or layers == cfg.n_layers:
-        return cfg, {}
+        return cfg, cuts
     if not 0 < layers < cfg.n_layers:
         raise ValueError(f"--layers must be in 1..{cfg.n_layers}")
     return (dataclasses.replace(cfg, n_layers=layers),
-            {"n_layers": [layers, cfg.n_layers]})
+            {"n_layers": [layers, cfg.n_layers], **cuts})
 
 
 def main(argv=None) -> dict:
@@ -152,8 +156,7 @@ def main(argv=None) -> dict:
     specs = api.state_specs(cfg)
     oc = adamw.AdamWConfig(lr=args.lr, warmup_steps=10,
                            total_steps=max(args.steps * 2, 100))
-    loss_fn = api.make_eval_loss(cfg, run)
-    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+    grad_fn = api.make_grad_step(cfg, run)
 
     def apply_fn(state, grads):
         # eager AdamW: the span holds the host's dispatch of its per-leaf
@@ -293,6 +296,8 @@ def main(argv=None) -> dict:
     tokens = args.steps * args.micro * args.batch * args.seq
     summary = {
         "arch": cfg.name, "reduced": cuts,
+        "params": cfg.param_count(),
+        "active_params": cfg.active_param_count(),
         "steps": args.steps, "wall_s": round(wall, 2),
         "setup_s": round(setup_s, 2),
         "step_s": [round(x, 3) for x in step_s],

@@ -17,6 +17,7 @@ from repro.distributed.sharding import TensorSpec
 from repro.models import encdec, lm
 from repro.models.layers import softmax_cross_entropy
 from repro.models.lm import RunConfig
+from repro.moe.moe import ROUTING_COUNTS
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig, AdamWState
 
@@ -123,11 +124,33 @@ def make_decode_step(cfg: ArchConfig, run: RunConfig = RunConfig()):
 
 
 def make_eval_loss(cfg: ArchConfig, run: RunConfig = RunConfig()):
-    def eval_loss(params, batch: dict):
+    """The volunteer round's loss: mean next-token cross entropy.
+
+    The returned function's ``with_routing`` gives ``(loss, routing)``:
+    the per-layer counts ``moe.moe_apply`` reports, or nothing for a model
+    with no experts."""
+    def with_routing(params, batch: dict):
         if cfg.enc_dec:
-            logits, _ = encdec.forward_train(
+            logits, metrics = encdec.forward_train(
                 params, cfg, batch["frames"], batch["tokens"], run)
         else:
-            logits, _ = lm.forward_train(params, cfg, batch["tokens"], run)
-        return softmax_cross_entropy(logits, batch["labels"], cfg.vocab_size)
+            logits, metrics = lm.forward_train(params, cfg, batch["tokens"],
+                                               run)
+        routing = {k: v for k, v in metrics.items() if k in ROUTING_COUNTS}
+        return softmax_cross_entropy(logits, batch["labels"],
+                                     cfg.vocab_size), routing
+
+    def eval_loss(params, batch: dict):
+        return with_routing(params, batch)[0]
+    eval_loss.with_routing = with_routing
     return eval_loss
+
+
+def make_grad_step(cfg: ArchConfig, run: RunConfig = RunConfig()):
+    """Jitted ``(params, batch) -> ((loss, routing), grads)`` of
+    ``make_eval_loss``'s loss, with its routing counts as aux.  A loss
+    wrapped around that one (no ``with_routing``) reports no routing."""
+    loss_fn = make_eval_loss(cfg, run)
+    with_routing = getattr(loss_fn, "with_routing",
+                           lambda params, batch: (loss_fn(params, batch), {}))
+    return jax.jit(jax.value_and_grad(with_routing, has_aux=True))

@@ -13,7 +13,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, LayerKind
 from repro.distributed.sharding import TensorSpec, constrain
 from repro.models import layers
 from repro.models.layers import blocked_attention, decode_attention, rotary
@@ -48,7 +48,8 @@ def cache_specs(cfg: ArchConfig, batch: int, max_len: int,
                    TensorSpec(shape, axes, dtype))
 
 
-def _qkv(p: dict, x: jax.Array, cfg: ArchConfig, positions: jax.Array):
+def _qkv(p: dict, x: jax.Array, cfg: ArchConfig, positions: jax.Array,
+         kind: LayerKind):
     dt = x.dtype
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", x, p["wk"].astype(dt))
@@ -57,30 +58,28 @@ def _qkv(p: dict, x: jax.Array, cfg: ArchConfig, positions: jax.Array):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    q = rotary(q, positions, cfg.rope_theta)
-    k = rotary(k, positions, cfg.rope_theta)
+    q = rotary(q, positions, kind.rope)
+    k = rotary(k, positions, kind.rope)
     return q, k, v
 
 
 def attn_train(p: dict, x: jax.Array, cfg: ArchConfig,
-               positions: jax.Array, *, causal: bool = True) -> jax.Array:
-    """Full-sequence attention (training / encoder)."""
-    q, k, v = _qkv(p, x, cfg, positions)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    out = blocked_attention(q, layers.repeat_kv(k, rep),
-                            layers.repeat_kv(v, rep),
-                            causal=causal, window=cfg.window)
+               positions: jax.Array, kind: LayerKind, *, causal: bool = True,
+               block: int = 1024) -> jax.Array:
+    """Full-sequence attention (training / encoder) of a ``kind`` layer."""
+    q, k, v = _qkv(p, x, cfg, positions, kind)
+    out = blocked_attention(q, k, v, causal=causal, window=kind.window,
+                            block_q=block, block_kv=block)
     return jnp.einsum("bthk,hkd->btd", out, p["wo"].astype(x.dtype))
 
 
 def attn_prefill(p: dict, x: jax.Array, cfg: ArchConfig,
-                 positions: jax.Array) -> tuple[jax.Array, KVCache]:
+                 positions: jax.Array, kind: LayerKind, *,
+                 block: int = 1024) -> tuple[jax.Array, KVCache]:
     """Causal attention that also returns the layer's KV cache."""
-    q, k, v = _qkv(p, x, cfg, positions)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    out = blocked_attention(q, layers.repeat_kv(k, rep),
-                            layers.repeat_kv(v, rep),
-                            causal=True, window=cfg.window)
+    q, k, v = _qkv(p, x, cfg, positions, kind)
+    out = blocked_attention(q, k, v, causal=True, window=kind.window,
+                            block_q=block, block_kv=block)
     out = jnp.einsum("bthk,hkd->btd", out, p["wo"].astype(x.dtype))
     return out, KVCache(k, v)
 
@@ -107,13 +106,14 @@ def _cache_write(cache: jax.Array, new: jax.Array,
 
 
 def attn_decode(p: dict, x: jax.Array, cfg: ArchConfig, cache: KVCache,
-                index: jax.Array) -> tuple[jax.Array, KVCache]:
+                index: jax.Array, kind: LayerKind
+                ) -> tuple[jax.Array, KVCache]:
     """One-token decode step.  x: (B, 1, D); index: () or (B,) lengths."""
     b = x.shape[0]
     index = jnp.asarray(index, jnp.int32)
     positions = jnp.full((b, 1), index, jnp.int32) if index.ndim == 0 \
         else index[:, None]
-    q, k, v = _qkv(p, x, cfg, positions)
+    q, k, v = _qkv(p, x, cfg, positions, kind)
     # q is tiny: replicate it across the model axis so the scores einsum
     # keeps the CACHE's len-sharding instead of resharding the cache onto
     # q's head sharding (50 KB gather vs GBs).
@@ -127,6 +127,6 @@ def attn_decode(p: dict, x: jax.Array, cfg: ArchConfig, cache: KVCache,
         else index + 1
     out = decode_attention(q, layers.repeat_kv(k_cache, rep),
                            layers.repeat_kv(v_cache, rep), kv_len=kv_len,
-                           window=cfg.window)
+                           window=kind.window)
     out = jnp.einsum("bthk,hkd->btd", out, p["wo"].astype(x.dtype))
     return out, KVCache(k_cache, v_cache)

@@ -83,7 +83,7 @@ def encode(params: dict, cfg: ArchConfig, frames: jax.Array,
     def body(x, lp):
         xn = layers.rms_norm(x, lp["ln1"], cfg.rms_eps)
         x = x + attention.attn_train(lp["attn"], xn, cfg, positions,
-                                     causal=False)
+                                     cfg.layer_kinds()[0], causal=False)
         xn2 = layers.rms_norm(x, lp["ln2"], cfg.rms_eps)
         m = lp["mlp"]
         x = x + layers.swiglu(xn2, m["w_gate"], m["w_up"], m["w_down"])
@@ -129,7 +129,8 @@ def forward_train(params: dict, cfg: ArchConfig, frames: jax.Array,
 
     def body(x, lp):
         xn = layers.rms_norm(x, lp["ln1"], cfg.rms_eps)
-        x = x + attention.attn_train(lp["self_attn"], xn, cfg, positions)
+        x = x + attention.attn_train(lp["self_attn"], xn, cfg, positions,
+                                     cfg.layer_kinds()[0])
         xc = layers.rms_norm(x, lp["ln_x"], cfg.rms_eps)
         x = x + _cross_attend(lp["cross_attn"], xc, cfg, None, enc_out)
         xn2 = layers.rms_norm(x, lp["ln2"], cfg.rms_eps)
@@ -160,7 +161,7 @@ def prefill(params: dict, cfg: ArchConfig, frames: jax.Array,
         dt = x.dtype
         xn = layers.rms_norm(x, lp["ln1"], cfg.rms_eps)
         a, self_kv = attention.attn_prefill(lp["self_attn"], xn, cfg,
-                                            positions)
+                                            positions, cfg.layer_kinds()[0])
         x = x + a
         xc = layers.rms_norm(x, lp["ln_x"], cfg.rms_eps)
         ck = jnp.einsum("btd,dhk->bthk", enc_out,
@@ -193,7 +194,8 @@ def decode_step(params: dict, cfg: ArchConfig, caches: dict,
         lp, cache = lp_cache
         xn = layers.rms_norm(x, lp["ln1"], cfg.rms_eps)
         a, self_kv = attention.attn_decode(lp["self_attn"], xn, cfg,
-                                           cache["self_kv"], index)
+                                           cache["self_kv"], index,
+                                           cfg.layer_kinds()[0])
         x = x + a
         xc = layers.rms_norm(x, lp["ln_x"], cfg.rms_eps)
         x = x + _cross_attend(lp["cross_attn"], xc, cfg, cache["cross_kv"],

@@ -5,7 +5,10 @@ MoE (deepseek/qwen3), SSM (falcon-mamba), hybrid attn+SSM (hymba) and the
 early-fusion VLM backbone (chameleon — VQ image ids share the token vocab;
 the VQ tokenizer itself is the stubbed frontend).  Layers are stacked and
 scanned (``lax.scan``) so the HLO stays compact for 48–64 layer configs; the
-per-layer body is optionally rematerialized.
+per-layer body is optionally rematerialized.  Where the layers differ in
+kind (window, rotary positions: ``ArchConfig.layer_kinds``) the scan runs
+over whole periods of the pattern and the period is unrolled in its body;
+parameter shapes and leaf paths are the same either way.
 
 Everything is a pure function over an explicit ``TensorSpec`` param tree —
 this *is* the "compile once, run on any volunteer mesh" property the capsule
@@ -20,11 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, LayerKind
 from repro.distributed.sharding import TensorSpec, constrain, stack_specs
 from repro.models import attention, layers, ssm
 from repro.models.attention import KVCache
-from repro.moe.moe import moe_apply, moe_specs
+from repro.moe.moe import ROUTING_COUNTS, moe_apply, moe_specs
 
 ACT = ("act_batch", "act_seq", "act_embed")
 
@@ -50,7 +53,6 @@ class RunConfig:
     remat: str = "full"              # none | full | dots
     block_kv: int = 1024
     ssm_chunk: int = 256
-    capacity_factor: float = 1.25
     compute_dtype: Any = jnp.bfloat16
     logical_rules: Optional[dict] = None   # sharding-rule overrides
     # FSDP semantics: gather each layer's (sharded) weights to replicated
@@ -109,25 +111,62 @@ def cache_specs(cfg: ArchConfig, batch: int, max_len: int) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# The layer scan
+# ---------------------------------------------------------------------------
+def layer_period(kinds: tuple) -> int:
+    """Length of the shortest pattern that repeats to give ``kinds``."""
+    n = len(kinds)
+    for p in range(1, n + 1):
+        if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n)):
+            return p
+    return n
+
+
+def scan_layers(cfg: ArchConfig, body, x, xs, policy=None):
+    """``lax.scan`` of ``body(x, layer_xs, kind) -> (x, layer_ys)`` over the
+    stacked ``xs`` (leading dim ``n_layers``), giving each layer its kind.
+
+    The scan runs over whole periods of the kinds' pattern, the period
+    unrolled inside: stacked arrays are viewed as ``(periods, period, ...)``
+    and the outputs put back as ``(n_layers, ...)``."""
+    kinds = cfg.layer_kinds()
+    n, per = len(kinds), layer_period(kinds)
+
+    def period_body(x, pxs):
+        ys = []
+        for j in range(per):
+            x, y = body(x, jax.tree.map(lambda a: a[j], pxs), kinds[j])
+            ys.append(y)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    if policy is not None:
+        period_body = jax.checkpoint(period_body, policy=policy)
+    xs = jax.tree.map(lambda a: a.reshape((n // per, per) + a.shape[1:]), xs)
+    x, ys = lax.scan(period_body, x, xs)
+    return x, jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), ys)
+
+
+# ---------------------------------------------------------------------------
 # Blocks
 # ---------------------------------------------------------------------------
 def _block_train(cfg: ArchConfig, run: RunConfig, p: dict, x: jax.Array,
-                 positions: jax.Array, causal: bool = True):
+                 positions: jax.Array, kind: LayerKind, causal: bool = True):
     metrics = {}
     xn = layers.rms_norm(x, p["ln1"], cfg.rms_eps)
     if cfg.family == "ssm":
         return x + ssm.ssm_train(p["ssm"], xn, cfg, run.ssm_chunk), metrics
     if cfg.family == "hybrid":
-        a = attention.attn_train(p["attn"], xn, cfg, positions, causal=causal)
+        a = attention.attn_train(p["attn"], xn, cfg, positions, kind,
+                                 causal=causal, block=run.block_kv)
         s = ssm.ssm_train(p["ssm"], xn, cfg, run.ssm_chunk)
         x = x + 0.5 * (layers.rms_norm(a, p["norm_attn"], cfg.rms_eps)
                        + layers.rms_norm(s, p["norm_ssm"], cfg.rms_eps))
     else:
-        x = x + attention.attn_train(p["attn"], xn, cfg, positions,
-                                     causal=causal)
+        x = x + attention.attn_train(p["attn"], xn, cfg, positions, kind,
+                                     causal=causal, block=run.block_kv)
     xn2 = layers.rms_norm(x, p["ln2"], cfg.rms_eps)
     if cfg.is_moe:
-        y, metrics = moe_apply(p["moe"], xn2, cfg, run.capacity_factor)
+        y, metrics = moe_apply(p["moe"], xn2, cfg)
         x = x + y
     else:
         m = p["mlp"]
@@ -136,7 +175,7 @@ def _block_train(cfg: ArchConfig, run: RunConfig, p: dict, x: jax.Array,
 
 
 def _block_decode(cfg: ArchConfig, run: RunConfig, p: dict, x: jax.Array,
-                  cache: dict, index: jax.Array):
+                  cache: dict, index: jax.Array, kind: LayerKind):
     new_cache = {}
     xn = layers.rms_norm(x, p["ln1"], cfg.rms_eps)
     if cfg.family == "ssm":
@@ -144,17 +183,17 @@ def _block_decode(cfg: ArchConfig, run: RunConfig, p: dict, x: jax.Array,
         return x + y, new_cache
     if cfg.family == "hybrid":
         a, new_cache["kv"] = attention.attn_decode(p["attn"], xn, cfg,
-                                                   cache["kv"], index)
+                                                   cache["kv"], index, kind)
         s, new_cache["ssm"] = ssm.ssm_decode(p["ssm"], xn, cfg, cache["ssm"])
         x = x + 0.5 * (layers.rms_norm(a, p["norm_attn"], cfg.rms_eps)
                        + layers.rms_norm(s, p["norm_ssm"], cfg.rms_eps))
     else:
         a, new_cache["kv"] = attention.attn_decode(p["attn"], xn, cfg,
-                                                   cache["kv"], index)
+                                                   cache["kv"], index, kind)
         x = x + a
     xn2 = layers.rms_norm(x, p["ln2"], cfg.rms_eps)
     if cfg.is_moe:
-        y, _ = moe_apply(p["moe"], xn2, cfg, run.capacity_factor)
+        y, _ = moe_apply(p["moe"], xn2, cfg)
         x = x + y
     else:
         m = p["mlp"]
@@ -187,19 +226,18 @@ def forward_train(params: dict, cfg: ArchConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     layer_params = cast_tree(params["layers"], run.compute_dtype)
 
-    def body(x, lp):
+    def body(x, lp, kind):
         lp = gather_weights(lp, run)
-        x, metrics = _block_train(cfg, run, lp, x, positions, causal)
+        x, metrics = _block_train(cfg, run, lp, x, positions, kind, causal)
         return constrain(x, ACT), metrics
 
-    policy = run.remat_policy()
-    if policy is not None:
-        body = jax.checkpoint(body, policy=policy)
-    x, ms = lax.scan(body, x, layer_params)
+    x, ms = scan_layers(cfg, body, x, layer_params, run.remat_policy())
     x = layers.rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = constrain(unembed(params, cfg, x),
                        ("act_batch", "act_seq", "act_vocab"))
-    metrics = {k: jnp.mean(v) for k, v in ms.items()} if ms else {}
+    # routing counts stay per layer; the rest is averaged over layers
+    metrics = {k: v if k in ROUTING_COUNTS else jnp.mean(v)
+               for k, v in ms.items()}
     return logits, metrics
 
 
@@ -215,7 +253,7 @@ def prefill(params: dict, cfg: ArchConfig, tokens: jax.Array, max_len: int,
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     layer_params = cast_tree(params["layers"], run.compute_dtype)
 
-    def body(x, lp):
+    def body(x, lp, kind):
         lp = gather_weights(lp, run)
         new_cache = {}
         xn = layers.rms_norm(x, lp["ln1"], cfg.rms_eps)
@@ -224,30 +262,29 @@ def prefill(params: dict, cfg: ArchConfig, tokens: jax.Array, max_len: int,
                                                 run.ssm_chunk, True)
             x = x + y
         elif cfg.family == "hybrid":
-            a, kv = attention.attn_prefill(lp["attn"], xn, cfg, positions)
+            a, kv = attention.attn_prefill(lp["attn"], xn, cfg, positions,
+                                           kind, block=run.block_kv)
             s, new_cache["ssm"] = ssm.ssm_train(lp["ssm"], xn, cfg,
                                                 run.ssm_chunk, True)
             x = x + 0.5 * (layers.rms_norm(a, lp["norm_attn"], cfg.rms_eps)
                            + layers.rms_norm(s, lp["norm_ssm"], cfg.rms_eps))
             new_cache["kv"] = _pad_cache(kv, max_len)
         else:
-            a, kv = attention.attn_prefill(lp["attn"], xn, cfg, positions)
+            a, kv = attention.attn_prefill(lp["attn"], xn, cfg, positions,
+                                           kind, block=run.block_kv)
             x = x + a
             new_cache["kv"] = _pad_cache(kv, max_len)
         if "ln2" in lp:
             xn2 = layers.rms_norm(x, lp["ln2"], cfg.rms_eps)
             if cfg.is_moe:
-                y, _ = moe_apply(lp["moe"], xn2, cfg, run.capacity_factor)
+                y, _ = moe_apply(lp["moe"], xn2, cfg)
                 x = x + y
             else:
                 m = lp["mlp"]
                 x = x + layers.swiglu(xn2, m["w_gate"], m["w_up"], m["w_down"])
         return constrain(x, ACT), new_cache
 
-    policy = run.remat_policy()
-    if policy is not None:
-        body = jax.checkpoint(body, policy=policy)
-    x, caches = lax.scan(body, x, layer_params)
+    x, caches = scan_layers(cfg, body, x, layer_params, run.remat_policy())
     x = layers.rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
     logits = unembed(params, cfg, x)[:, 0]
     return logits, caches
@@ -267,12 +304,12 @@ def decode_step(params: dict, cfg: ArchConfig, caches: dict,
     x = constrain(embed_tokens(params, cfg, tokens, run.compute_dtype), ACT)
     layer_params = cast_tree(params["layers"], run.compute_dtype)
 
-    def body(x, lp_cache):
+    def body(x, lp_cache, kind):
         lp, cache = lp_cache
-        x, new_cache = _block_decode(cfg, run, lp, x, cache, index)
+        x, new_cache = _block_decode(cfg, run, lp, x, cache, index, kind)
         return constrain(x, ACT), new_cache
 
-    x, new_caches = lax.scan(body, x, (layer_params, caches))
+    x, new_caches = scan_layers(cfg, body, x, (layer_params, caches))
     x = layers.rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = unembed(params, cfg, x)
     return logits, new_caches

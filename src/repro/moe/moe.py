@@ -1,24 +1,30 @@
-"""Mixture-of-Experts block (DeepSeekMoE / Qwen3-MoE style).
+"""Mixture-of-Experts block (DeepSeekMoE / Qwen3-MoE / Mellum2 style).
 
-TPU-native dispatch, two design decisions:
+Dropless routing over a device's share of the experts:
 
-* **Sort-based, FLOP-honest**: GShard one-hot dispatch einsums are memory-
-  hungry and count fake FLOPs (the roofline's useful-ratio would lie).  We
-  argsort (token, slot) pairs by expert id and *gather* into a dense
-  (B, E, C, D) buffer — zero matmul FLOPs in routing, real FLOPs only in
-  the expert matmuls.
+* **Routing over all experts.**  The router keeps its published width:
+  softmax over every expert, top-k, the k gates renormalised over the k
+  chosen.  Gates are set before anything is left out.
 
-* **Grouped per-DP-shard routing** (§Perf cell D): an earlier revision
-  sorted the GLOBAL flattened token set, which forced GSPMD to all-gather
-  every token across the data axis before routing (~36 s/step collective
-  for deepseek train_4k).  Routing is independent per token, so we sort
-  *within each batch row*: batch stays sharded on data, experts stay
-  sharded on model (EP), and the only cross-device traffic left is the
-  expert-combine partial-sum over the model axis.
+* **The experts held.**  Under expert parallelism a device holds
+  ``MoEConfig.held`` of the experts (all of them by default) and computes
+  the part of the result that its own experts give; the pairs routed to
+  experts held elsewhere are counted and left to those devices.  On one
+  device there is no exchange: what the absent experts would add is left
+  out, and that partial result goes on to the next layer.
 
-Over-capacity tokens are dropped (capacity-factor semantics) per (row,
-expert); the drop fraction is a reported metric.  Aux losses: switch-style
-load-balance + router z-loss.
+* **Dropless, grouped.**  The (token, slot) pairs are sorted by expert, the
+  held experts' pairs first, and each held expert multiplies its own rows
+  (grouped matrix products, sizes set by the routing).  The row buffer is
+  sized for the worst case (every token sends ``min(k, held)`` pairs
+  here), so no pair is dropped at any imbalance; the rows past the pairs
+  routed here are masked.  The grouped products and the gathers around
+  them are rematerialized in the backward pass instead of saving the
+  worst-case buffers.
+
+Counts per call: pairs computed here, pairs routed elsewhere, and the
+largest held expert's pairs.  Aux losses (switch-style load balance and
+router z-loss) are returned for the callers that add them.
 """
 from __future__ import annotations
 
@@ -31,15 +37,20 @@ from repro.configs.base import ArchConfig
 from repro.distributed.sharding import TensorSpec, constrain
 from repro.models import layers
 
+# the per-call routing counts, as ``moe_apply``'s metrics name them
+ROUTING_COUNTS = ("moe_pairs_here", "moe_pairs_elsewhere",
+                  "moe_max_expert_pairs")
+
 
 def moe_specs(cfg: ArchConfig) -> dict:
     d = cfg.d_model
     e, fe = cfg.moe.n_experts, cfg.moe.d_ff_expert
+    held = cfg.moe.held
     out = {
         "router": TensorSpec((d, e), ("embed", None)),
-        "w_gate": TensorSpec((e, d, fe), ("experts", "embed", "expert_ff")),
-        "w_up": TensorSpec((e, d, fe), ("experts", "embed", "expert_ff")),
-        "w_down": TensorSpec((e, fe, d), ("experts", "expert_ff", "embed")),
+        "w_gate": TensorSpec((held, d, fe), ("experts", "embed", "expert_ff")),
+        "w_up": TensorSpec((held, d, fe), ("experts", "embed", "expert_ff")),
+        "w_down": TensorSpec((held, fe, d), ("experts", "expert_ff", "embed")),
     }
     if cfg.moe.n_shared_experts:
         out["shared"] = layers.mlp_specs(
@@ -47,81 +58,75 @@ def moe_specs(cfg: ArchConfig) -> dict:
     return out
 
 
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """Rows ``[sum(g[:i]), sum(g[:i+1]))`` of ``lhs`` times ``rhs[i]``;
+    rows past ``sum(g)`` are not computed (their values are undefined)."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _experts(x, rows, gates, group_sizes, w_gate, w_up, w_down):
+    """Each held expert's SwiGLU on its rows, gated, summed per token.
+
+    x: (N, D); rows: (M,) token of each buffer row (held experts' pairs
+    first, by expert); gates: (M,) the pair's gate, 0 past the pairs here."""
+    m = rows.shape[0]
+    valid = (jnp.arange(m) < group_sizes.sum())[:, None]
+    dt = x.dtype
+    xin = jnp.where(valid, x[rows], 0).astype(dt)
+    g = grouped_matmul(xin, w_gate.astype(dt), group_sizes)
+    u = grouped_matmul(xin, w_up.astype(dt), group_sizes)
+    h = (jax.nn.silu(g) * u).astype(dt)
+    y = grouped_matmul(h, w_down.astype(dt), group_sizes)
+    y = jnp.where(valid, y, 0) * gates[:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[rows].add(y).astype(dt)
+
+
 def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig,
-              capacity_factor: float = 1.25) -> Tuple[jax.Array, dict]:
-    """x: (B, T, D) -> (y, metrics).  Differentiable through gates."""
+              first_held: int = 0) -> Tuple[jax.Array, dict]:
+    """x: (B, T, D) -> (y, metrics).  Differentiable through gates.
+
+    This device holds experts ``first_held .. first_held + held - 1`` (the
+    leading dimension of ``p["w_gate"]``); the router scores all
+    ``cfg.moe.n_experts``."""
     b, t, d = x.shape
     e, k = cfg.moe.n_experts, cfg.moe.top_k
-    n_items = t * k                                    # per-row (token,slot)s
+    held = p["w_gate"].shape[0]
+    n = b * t
+    xf = x.reshape(n, d)
 
-    logits = (x.astype(jnp.float32)
-              @ p["router"].astype(jnp.float32))                  # (B,T,E)
+    # routing decisions in float32 at full precision: a rounded score
+    # moves a pair to another expert
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)         # (N,E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, k)               # (B,T,k)
+    gate_vals, expert_ids = jax.lax.top_k(probs, k)               # (N,k)
     gate_vals = gate_vals / jnp.maximum(
         gate_vals.sum(-1, keepdims=True), 1e-9)                   # renormalize
 
     # ---- aux losses (global means; cheap scalars) ----
-    me = probs.mean((0, 1))                                       # (E,)
-    ce = jnp.zeros((b, e), jnp.float32).at[
-        jnp.arange(b)[:, None], expert_ids.reshape(b, -1)].add(
-        1.0 / (b * n_items)).sum(0) * 1.0
+    me = probs.mean(0)                                            # (E,)
+    ce = jnp.zeros((e,), jnp.float32).at[expert_ids.reshape(-1)].add(
+        1.0 / (n * k))
     aux = e * jnp.sum(me * ce)
     zloss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
-    # ---- grouped (per batch row) sort-based dispatch ----
-    flat_expert = expert_ids.reshape(b, n_items)                  # (B,I)
-    flat_gate = gate_vals.reshape(b, n_items)
-    flat_token = jnp.broadcast_to(
-        jnp.arange(t, dtype=jnp.int32)[:, None], (t, k)).reshape(1, n_items)
-    flat_token = jnp.broadcast_to(flat_token, (b, n_items))
-    order = jnp.argsort(flat_expert, axis=-1)                     # stable
-    take = lambda a: jnp.take_along_axis(a, order, axis=-1)       # noqa: E731
-    sorted_expert = take(flat_expert)
-    sorted_token = take(flat_token)
-    sorted_gate = take(flat_gate)
+    # ---- the held experts' pairs, sorted by expert, first ----
+    local = expert_ids.reshape(-1) - first_held                   # (N*k,)
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)                            # held = away
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    group_sizes = counts[:held]
+    m = n * min(k, held)              # most pairs one token set can send here
+    pairs = order[:m]
+    rows = (pairs // k).astype(jnp.int32)
+    gates = jnp.where(jnp.arange(m) < group_sizes.sum(),
+                      gate_vals.reshape(-1)[pairs], 0.0)
 
-    cap = max(int(capacity_factor * n_items / e), 1)
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    counts = jnp.zeros((b, e), jnp.int32).at[
-        jnp.broadcast_to(rows, (b, n_items)), sorted_expert].add(1)
-    offsets = jnp.concatenate(
-        [jnp.zeros((b, 1), counts.dtype), jnp.cumsum(counts, -1)[:, :-1]],
-        axis=-1)                                                  # (B,E)
-    rank = jnp.arange(n_items) - jnp.take_along_axis(
-        offsets, sorted_expert, axis=-1)                          # (B,I)
-    keep = rank < cap                                             # capacity
-
-    # gather tokens into the (B, E, C, D) expert buffer (local per shard)
-    slot_pos = offsets[:, :, None] + jnp.arange(cap)[None, None]  # (B,E,C)
-    slot_valid = jnp.arange(cap)[None, None] < \
-        jnp.minimum(counts, cap)[:, :, None]
-    slot_pos = jnp.clip(slot_pos, 0, n_items - 1)
-    tok_for_slot = jnp.take_along_axis(
-        sorted_token.reshape(b, 1, n_items),
-        slot_pos.reshape(b, 1, e * cap), axis=-1).reshape(b, e, cap)
-    xin = jnp.take_along_axis(
-        x[:, None], tok_for_slot[..., None].astype(jnp.int32), axis=2) \
-        * slot_valid[..., None].astype(x.dtype)                   # (B,E,C,D)
-    xin = constrain(xin, ("act_batch", "experts", None, None))
-
-    # expert MLPs — the only matmul FLOPs in this block (E sharded: EP)
-    dt = x.dtype
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", xin,
-                               p["w_gate"].astype(dt))) \
-        * jnp.einsum("becd,edf->becf", xin, p["w_up"].astype(dt))
-    yexp = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(dt))
-
-    # combine: scatter-add back per row; partial sums over expert shards
-    # become ONE model-axis all-reduce of (B_local, T, D)
-    out_idx = jnp.where(keep, sorted_token, t)                    # drop -> bin T
-    gate_w = jnp.where(keep, sorted_gate, 0.0)
-    item_slot = sorted_expert * cap + jnp.clip(rank, 0, cap - 1)  # (B,I)
-    item_y = jnp.take_along_axis(
-        yexp.reshape(b, e * cap, d), item_slot[..., None], axis=1)  # (B,I,D)
-    y = jnp.zeros((b, t + 1, d), item_y.dtype).at[
-        jnp.broadcast_to(rows, (b, n_items)), out_idx].add(
-        item_y * gate_w[..., None].astype(item_y.dtype))[:, :t]
+    y = jax.checkpoint(_experts)(xf, rows, gates, group_sizes, p["w_gate"],
+                                 p["w_up"], p["w_down"]).reshape(b, t, d)
     y = constrain(y, ("act_batch", "act_seq", "act_embed"))
 
     if "shared" in p:
@@ -131,6 +136,8 @@ def moe_apply(p: dict, x: jax.Array, cfg: ArchConfig,
     metrics = {
         "moe_aux": aux,
         "moe_zloss": zloss,
-        "moe_drop_frac": 1.0 - keep.mean(),
+        "moe_pairs_here": group_sizes.sum(),
+        "moe_pairs_elsewhere": counts[held],
+        "moe_max_expert_pairs": group_sizes.max(),
     }
     return y, metrics

@@ -70,7 +70,7 @@ def test_loss_near_uniform_at_init(arch):
 def test_prefill_decode_matches_teacher_forced(arch):
     cfg = reduced(get_arch(arch))
     run = RunConfig(remat="none", block_kv=8, ssm_chunk=8,
-                    compute_dtype=jnp.float32, capacity_factor=8.0)
+                    compute_dtype=jnp.float32)
     params = init_tree(api.param_specs(cfg), jax.random.key(1))
     B, T, MAX = 2, 12, 20
     r = np.random.default_rng(1)
@@ -94,7 +94,12 @@ def test_prefill_decode_matches_teacher_forced(arch):
 def test_moe_routing_stats():
     cfg = reduced(get_arch("deepseek-moe-16b"))
     params = init_tree(api.param_specs(cfg), jax.random.key(0))
-    logits, metrics = lm.forward_train(
-        params, cfg, _batch(cfg)["tokens"], RUN)
-    assert float(metrics["moe_drop_frac"]) < 0.5
+    tokens = _batch(cfg)["tokens"]
+    logits, metrics = lm.forward_train(params, cfg, tokens, RUN)
+    # dropless: every (token, slot) pair of every layer is computed here
+    pairs = tokens.size * cfg.moe.top_k
+    np.testing.assert_array_equal(metrics["moe_pairs_here"],
+                                  [pairs] * cfg.n_layers)
+    np.testing.assert_array_equal(metrics["moe_pairs_elsewhere"],
+                                  [0] * cfg.n_layers)
     assert float(metrics["moe_aux"]) > 0.5     # ~1.0 when balanced

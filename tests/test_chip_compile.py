@@ -67,3 +67,35 @@ def test_delta_apply_compiles(one_chip):
     nblk = -(-LEAF[0] * LEAF[1] // (SUB * LANE))
     delta = _spec((nblk, SUB, LANE), jnp.int32, one_chip)
     _assert_kernel(delta_apply.lower(leaf, delta).compile())
+
+
+def test_expert_layer_compiles_at_mellum_widths(one_chip):
+    """Mellum2's EP-8 share, forward and backward: 2 x 4,096 tokens routed
+    over 64 experts, the grouped products of the 8 held."""
+    from repro.configs.base import get_arch
+    from repro.moe.moe import moe_apply, moe_specs
+    cfg = get_arch("mellum2-12b-a2.5b-ep8")
+    p = {k: _spec(s.shape, jnp.float32, one_chip)
+         for k, s in moe_specs(cfg).items()}
+    x = _spec((2, 4096, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        return jnp.sum(moe_apply(p, x, cfg)[0].astype(jnp.float32))
+    jax.jit(jax.grad(loss, (0, 1))).lower(p, x).compile()
+
+
+@pytest.mark.parametrize("window", [1024, 0], ids=["sliding", "full"])
+def test_blocked_attention_backward_keeps_no_scores(one_chip, window):
+    """Mellum2's attention at 4,096 positions, forward and backward: the
+    backward pass recomputes scores, so its temporaries stay far below
+    the 8.6 GB that one layer's saved scores would take."""
+    from repro.models.layers import blocked_attention
+    q = _spec((2, 4096, 32, 128), jnp.bfloat16, one_chip)
+    kv = _spec((2, 4096, 4, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(blocked_attention(
+            q, k, v, causal=True, window=window, block_q=512,
+            block_kv=512).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
